@@ -74,8 +74,6 @@ def _cost(fn, *args):
     """(bytes_accessed, flops) from the compiled computation."""
     comp = jax.jit(fn).lower(*args).compile()
     ca = comp.cost_analysis()
-    if isinstance(ca, list):    # older jax returns a per-computation list
-        ca = ca[0] if ca else {}
     return float(ca.get("bytes accessed", float("nan"))), \
         float(ca.get("flops", float("nan")))
 

@@ -2,7 +2,7 @@
 
 The AST layer (:mod:`repro.analysis.rules`) sees source; this layer sees
 what JAX actually traces, which is where the paper's complexity story
-lives or dies. Three invariants:
+lives or dies. Four invariants:
 
 * **f64-free** — with f32 inputs, no equation converts to float64 and no
   output is float64. A stray `np.float64` constant or Python-scalar
@@ -13,6 +13,12 @@ lives or dies. Three invariants:
 * **host-callback-free** — no ``pure_callback`` / ``io_callback`` /
   ``debug_callback`` equations: a callback inside the solver forces a
   device→host round trip per CG iteration.
+* **full-precision f32 contractions** — every f32 ``dot_general`` in the
+  fit objective, the polish program and the posteriors asks for
+  ``Precision.HIGHEST``. XLA's default on TPU is one bf16 pass, which the
+  Gram's distance expansion and the CG residuals cannot survive; on the
+  CPU the default is already exact, so only this audit sees a missing
+  flag before the chip does.
 * **retrace-free refits** — two ``refit`` rounds on same-shaped data must
   reuse ONE compiled objective (``core.state._VG_CACHE`` entry with jit
   cache size 1). Before PR 6 every refit rebuilt a fresh closure and
@@ -26,8 +32,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
-__all__ = ["iter_eqns", "find_f64", "find_host_callbacks", "audit_mll",
+__all__ = ["iter_eqns", "find_f64", "find_host_callbacks",
+           "find_low_precision_dots", "audit_mll", "audit_matmul_precision",
            "audit_fit_objective", "audit_posterior_final",
            "audit_fused_mvm", "audit_solvers", "audit_guarded_solves",
            "audit_dist_fused_mvm", "audit_refit_retrace",
@@ -48,10 +56,7 @@ def iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(value):
-    import jax.core as jcore
-    closed = getattr(jcore, "ClosedJaxpr", ())
-    raw = getattr(jcore, "Jaxpr", ())
-    if isinstance(value, (closed, raw)):
+    if isinstance(value, (ClosedJaxpr, Jaxpr)):
         yield value
     elif isinstance(value, (list, tuple)):
         for v in value:
@@ -86,6 +91,24 @@ def find_host_callbacks(jaxpr) -> list[str]:
     return [f"host callback: {eqn.primitive.name}"
             for eqn in iter_eqns(jaxpr)
             if eqn.primitive.name in _CALLBACK_PRIMS]
+
+
+def find_low_precision_dots(jaxpr) -> list[str]:
+    """f32 ``dot_general`` equations that do not ask for HIGHEST precision."""
+    bad = []
+    for eqn in iter_eqns(jaxpr):
+        if (eqn.primitive.name != "dot_general"
+                or eqn.invars[0].aval.dtype != np.float32):
+            continue
+        prec = eqn.params.get("precision")
+        if prec is None or any(p != jax.lax.Precision.HIGHEST
+                               for p in prec):
+            frames = [f"{f.file_name.rsplit('/repro/', 1)[-1]}:{f.line_num}"
+                      for f in eqn.source_info.traceback.frames
+                      if "/repro/" in f.file_name]
+            bad.append(f"f32 dot_general at precision {prec} "
+                       f"({frames[0] if frames else 'outside repro'})")
+    return bad
 
 
 # --------------------------------------------------------------------------
@@ -176,6 +199,62 @@ def audit_posterior_final() -> list[str]:
 
     jaxpr = jax.make_jaxpr(final_of)(jnp.asarray(Y, jnp.float32))
     return _audit_jaxpr("Posterior.final", jaxpr)
+
+
+def audit_matmul_precision() -> list[str]:
+    """f32 contractions ask for HIGHEST precision on the chip's paths.
+
+    Traced per engine (dense, iterative, pallas): the polish program (the
+    MLL's value and gradient inside the device L-BFGS) and the lazy
+    posterior's mean and ``final``; plus the service's batched exact
+    posterior (products and covariance).
+    """
+    import dataclasses
+
+    from repro.core.engines import get_engine
+    from repro.core.posterior import (Posterior, _batched_cov_fn,
+                                      _batched_products_fn)
+    from repro.core.slq import rademacher_probes
+    from repro.core.state import (LKGPConfig, _cached_polish,
+                                  _flatten_params, fit, fit_batch,
+                                  init_params)
+
+    X, t, Y, mask = _problem()
+    d = X.shape[1]
+    failures = []
+    for backend in ("dense", "iterative", "pallas"):
+        cfg = LKGPConfig(backend=backend, polish_steps=1)
+        engine = get_engine(backend)
+        probes = rademacher_probes(
+            # Trace-only fixtures in separate audits; streams never mix.
+            jax.random.PRNGKey(0),  # lint: disable=RA101
+            cfg.slq_probes, jnp.asarray(mask), jnp.float32)
+        polish = _cached_polish(cfg, engine, d, 1)
+        x0 = _flatten_params(init_params(d, jnp.float32))
+        jaxpr = jax.make_jaxpr(polish)(x0, X, t, Y, mask, probes)
+        failures += [f"polish[{backend}]: {msg}"
+                     for msg in find_low_precision_dots(jaxpr)]
+
+        state = fit(X, t, Y, mask, cfg)
+
+        def products(Y_):
+            post = Posterior(dataclasses.replace(state, Y=Y_), engine=engine)
+            return post.final(), post.mean
+
+        jaxpr = jax.make_jaxpr(products)(jnp.asarray(Y))
+        failures += [f"posterior[{backend}]: {msg}"
+                     for msg in find_low_precision_dots(jaxpr)]
+
+    st = fit_batch(np.stack([X, X]), t, np.stack([Y, Y]),
+                   np.stack([mask, mask]), LKGPConfig(polish_steps=1))
+    for kind, build in (("products", _batched_products_fn),
+                        ("cov", _batched_cov_fn)):
+        fn = build(st.config.t_kernel, st.config.jitter)
+        jaxpr = jax.make_jaxpr(fn)(st.params, st.X, st.t, st.Y, st.mask,
+                                   st.x_tf, st.t_tf, st.y_tf)
+        failures += [f"batched_posterior[{kind}]: {msg}"
+                     for msg in find_low_precision_dots(jaxpr)]
+    return failures
 
 
 def audit_fused_mvm() -> list[str]:
@@ -404,6 +483,7 @@ def run_all_audits(verbose: bool = False) -> list[str]:
     """Run every auditor; returns the list of failure messages."""
     audits = [("mll f64/callback", audit_mll),
               ("fit objective f64/callback", audit_fit_objective),
+              ("f32 matmul precision", audit_matmul_precision),
               ("Posterior.final f64/callback", audit_posterior_final),
               ("fused MVM f64/callback", audit_fused_mvm),
               ("solver stack f64/callback", audit_solvers),

@@ -1,8 +1,10 @@
 """Static VMEM budget checker for the fused latent-Kronecker MVM kernel.
 
-``lk_mvm_fused`` (:mod:`repro.kernels.lk_mvm`) keeps, per grid step, a
-K1 block, a full U row strip, a full mask row strip, a full K2 column
-strip, the output block, and three f32 scratch tiles resident in VMEM.
+The fused kernel (:mod:`repro.kernels.lk_mvm`, shared by ``lk_mvm_fused``
+and ``lk_mvm_fused_rows``) keeps, per grid step, a K1 block, a full row
+strip of the masked input ``mask * U``, a full K2 column strip, the
+(i, j) mask and U tiles of the epilogue, the output block, and one f32
+accumulator resident in VMEM.
 TPU VMEM is ~16 MiB per core; a (block_n, block_m) choice whose resident
 set exceeds it fails at ``pallas_call`` compile time on hardware — long
 after the autotuner committed to it, and invisibly on CPU where the
@@ -11,16 +13,19 @@ implied by a block choice (including (sublane, lane) tile rounding and
 the pipeline's double buffering) so oversized configurations are rejected
 *before* ``pallas_call`` ever runs:
 
+* :func:`effective_blocks` — the block edges the kernels really use: an
+  axis that one block covers is taken whole, a tiled axis uses the block
+  rounded up to the 128-lane tile (Mosaic accepts no other edge);
 * :func:`fused_vmem_breakdown` / :func:`fused_vmem_bytes` — the byte
   model, mirroring the kernel's BlockSpecs one-to-one;
 * :func:`check_fused_blocks` — raise :class:`VmemBudgetError` when a
-  choice exceeds the budget (called by ``lk_mvm_fused`` itself);
+  choice exceeds the budget (called by the fused kernels themselves);
 * :func:`best_fitting_blocks` — the largest-throughput candidate pair
   that fits (used by the autotuner to filter its sweep);
 * :func:`audit_candidate_space` — sweep representative shape buckets and
   report every (shape, candidate) combination the autotuner could emit
-  that does not fit; after PR 6 the *filtered* sweep is provably clean
-  while the raw {64, 128, 256} grid is not (see tests/test_analysis.py).
+  that does not fit; the *filtered* sweep is provably clean while the raw
+  {64, 128, 256} grid is not (see tests/test_analysis.py).
 
 Pure stdlib — importable (and CI-checkable) without jax.
 """
@@ -29,8 +34,9 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 __all__ = ["VMEM_BUDGET_BYTES", "VmemBudgetError", "VmemBreakdown",
-           "fused_vmem_breakdown", "fused_vmem_bytes", "check_fused_blocks",
-           "best_fitting_blocks", "audit_candidate_space"]
+           "block_edge", "effective_blocks", "fused_vmem_breakdown",
+           "fused_vmem_bytes", "check_fused_blocks", "best_fitting_blocks",
+           "audit_candidate_space"]
 
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024   # 16 MiB per TPU core
 
@@ -38,10 +44,7 @@ VMEM_BUDGET_BYTES = 16 * 1024 * 1024   # 16 MiB per TPU core
 _CANDIDATES = (64, 128, 256)
 _MIN_EDGE = {"f32": 8, "bf16": 16}
 _ITEMSIZE = {"f32": 4, "bf16": 2}
-# itemsize -> sublane multiple; lane is always 128. The 8-byte entry
-# covers f64 outputs in interpret-mode tests (x64 enabled on CPU; real
-# TPUs never see f64 tiles).
-_SUBLANE = {4: 8, 2: 16, 8: 8}
+_SUBLANE = {4: 8, 2: 16}   # itemsize -> sublane multiple
 _LANE = 128
 
 
@@ -60,25 +63,42 @@ def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
     return r * c * itemsize
 
 
+def block_edge(block: int, extent: int, min_edge: int = 8) -> int:
+    """Edge of a block along an axis of length ``extent``.
+
+    Mosaic tiles the last two dimensions of every block in (sublane,
+    lane) = (8, 128) units, unless the block spans the whole dimension.
+    So an axis that one block covers is taken whole (at least
+    ``min_edge``), and a tiled axis uses ``block`` rounded up to a lane
+    multiple.
+    """
+    edge = _round_up(block, _LANE)
+    return max(min_edge, extent) if edge >= extent else edge
+
+
 def effective_blocks(n: int, m: int, block_n: int, block_m: int,
                      precision: str = "f32") -> tuple[int, int, int]:
-    """(bn, bm, mpad) exactly as ``lk_mvm_fused`` derives them."""
+    """(bn, bm, mpad) exactly as the fused kernels derive them.
+
+    ``n`` is the length of the K1 sweep (the global row count for the
+    row-sharded kernel).
+    """
     min_edge = _MIN_EDGE[precision]
-    bn = min(block_n, max(min_edge, n))
-    bm = min(block_m, max(min_edge, m))
-    mpad = _round_up(m, bm)
-    return bn, bm, mpad
+    bn = block_edge(block_n, n, min_edge)
+    bm = block_edge(block_m, m, min_edge)
+    return bn, bm, _round_up(m, bm)
 
 
 @dataclass(frozen=True)
 class VmemBreakdown:
-    """Exact per-grid-step VMEM bytes of ``lk_mvm_fused``."""
+    """Exact per-grid-step VMEM bytes of the fused kernel."""
     k1_block: int        # (bn, bn) K1 tile
-    u_strip: int         # (bn, mpad) U row strip
-    mask_strip: int      # (bn, mpad) mask row strip
+    u_strip: int         # (bn, mpad) row strip of mask * U
     k2_strip: int        # (mpad, bm) K2 column strip
-    out_block: int       # (bn, bm) output tile
-    scratch: int         # 3 x (bn, bm) f32 (accumulator + epilogue tiles)
+    mask_tile: int       # (bn, bm) epilogue mask tile
+    u_tile: int          # (bn, bm) epilogue U tile
+    out_block: int       # (bn, bm) f32 output tile
+    scratch: int         # (bn, bm) f32 accumulator
     double_buffered: int # pipelined copies of inputs + output
     total: int
 
@@ -87,14 +107,14 @@ class VmemBreakdown:
 
 
 def fused_vmem_breakdown(n: int, m: int, block_n: int, block_m: int,
-                         precision: str = "f32",
-                         out_itemsize: int = 4) -> VmemBreakdown:
-    """Byte-exact VMEM model of one ``lk_mvm_fused`` grid step.
+                         precision: str = "f32") -> VmemBreakdown:
+    """Byte-exact VMEM model of one fused-kernel grid step.
 
     Mirrors the kernel's BlockSpecs: inputs and the output are double
-    buffered by the Pallas pipeline (two resident copies each); the three
-    scratch tiles are single f32 buffers. ``B`` does not appear: the batch
-    axis is the outermost grid dimension, one b per step.
+    buffered by the Pallas pipeline (two resident copies each); the
+    accumulator is a single f32 buffer. ``B`` does not appear: the batch
+    axis is the outermost grid dimension, one b per step. The output is
+    always f32 (the wrappers cast back to the caller's dtype).
     """
     if precision not in _ITEMSIZE:
         raise ValueError(f"precision must be 'f32' or 'bf16', "
@@ -103,39 +123,39 @@ def fused_vmem_breakdown(n: int, m: int, block_n: int, block_m: int,
     bn, bm, mpad = effective_blocks(n, m, block_n, block_m, precision)
     k1 = _tile_bytes(bn, bn, ib)
     u = _tile_bytes(bn, mpad, ib)
-    mask = _tile_bytes(bn, mpad, ib)
     k2 = _tile_bytes(mpad, bm, ib)
-    out = _tile_bytes(bn, bm, out_itemsize)
-    scratch = 3 * _tile_bytes(bn, bm, 4)
-    inputs_once = k1 + u + mask + k2
+    mask_tile = _tile_bytes(bn, bm, ib)
+    u_tile = _tile_bytes(bn, bm, ib)
+    out = _tile_bytes(bn, bm, 4)
+    scratch = _tile_bytes(bn, bm, 4)
+    inputs_once = k1 + u + k2 + mask_tile + u_tile
     double = inputs_once + out     # the second pipelined copy of each
     total = 2 * inputs_once + 2 * out + scratch
-    return VmemBreakdown(k1_block=k1, u_strip=u, mask_strip=mask,
-                         k2_strip=k2, out_block=out, scratch=scratch,
-                         double_buffered=double, total=total)
+    return VmemBreakdown(k1_block=k1, u_strip=u, k2_strip=k2,
+                         mask_tile=mask_tile, u_tile=u_tile, out_block=out,
+                         scratch=scratch, double_buffered=double, total=total)
 
 
 def fused_vmem_bytes(n: int, m: int, block_n: int, block_m: int,
-                     precision: str = "f32", out_itemsize: int = 4) -> int:
-    return fused_vmem_breakdown(n, m, block_n, block_m, precision,
-                                out_itemsize).total
+                     precision: str = "f32") -> int:
+    return fused_vmem_breakdown(n, m, block_n, block_m, precision).total
 
 
 def check_fused_blocks(n: int, m: int, block_n: int, block_m: int,
-                       precision: str = "f32", out_itemsize: int = 4,
+                       precision: str = "f32",
                        budget: int = VMEM_BUDGET_BYTES) -> VmemBreakdown:
     """Raise :class:`VmemBudgetError` if the choice exceeds the budget."""
-    bd = fused_vmem_breakdown(n, m, block_n, block_m, precision,
-                              out_itemsize)
+    bd = fused_vmem_breakdown(n, m, block_n, block_m, precision)
     if not bd.fits(budget):
         bn, bm, mpad = effective_blocks(n, m, block_n, block_m, precision)
         raise VmemBudgetError(
             f"lk_mvm_fused blocks (block_n={block_n}, block_m={block_m}) "
             f"at shape (n={n}, m={m}, {precision}) need {bd.total} bytes "
             f"of VMEM (> budget {budget}): the (bn={bn}, mpad={mpad}) row "
-            f"strips alone are {bd.u_strip + bd.mask_strip} bytes. Use "
-            "smaller blocks, or the two-stage kernel (fused=False) whose "
-            "intermediate lives in HBM.")
+            f"and column strips are {bd.u_strip + bd.k2_strip} bytes, "
+            "twice over when double buffered. Use smaller blocks, or the "
+            "two-stage kernel (fused=False) whose intermediate lives in "
+            "HBM.")
     return bd
 
 
@@ -147,7 +167,6 @@ def _grid_steps(n: int, m: int, bn: int, bm: int) -> int:
 
 
 def best_fitting_blocks(n: int, m: int, precision: str = "f32",
-                        out_itemsize: int = 4,
                         candidates: tuple[int, ...] = _CANDIDATES,
                         budget: int = VMEM_BUDGET_BYTES
                         ) -> tuple[int, int] | None:
@@ -163,8 +182,8 @@ def best_fitting_blocks(n: int, m: int, precision: str = "f32",
     best_key: tuple | None = None
     for bn in candidates:
         for bm in candidates:
-            if not fused_vmem_breakdown(n, m, bn, bm, precision,
-                                        out_itemsize).fits(budget):
+            if not fused_vmem_breakdown(n, m, bn, bm,
+                                        precision).fits(budget):
                 continue
             key = (_grid_steps(n, m, *effective_blocks(
                 n, m, bn, bm, precision)[:2]), -bn, -bm)

@@ -14,7 +14,7 @@ Four implementations are registered:
                     method), O(n^2 m + n m^2) per MVM.
 * ``pallas``      — the iterative engine with every MVM routed through the
                     Pallas TPU kernel (:mod:`repro.kernels.ops`); runs in
-                    interpret mode off-TPU so it is testable on CPU.
+                    interpret mode on the CPU so it is testable there.
 * ``distributed`` — the iterative engine over the shard_map row-sharded
                     operator (:mod:`repro.distributed.lkgp_dist`), reachable
                     from the top-level API via ``LKGPConfig(backend=...)``.
@@ -26,6 +26,7 @@ engines use the custom-VJP quadratic-form gradient trick (Gardner et al.,
 """
 from __future__ import annotations
 
+import functools
 import math
 import threading
 from typing import Callable, Protocol, runtime_checkable
@@ -34,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from .caching import LRUCache
+from .gp_kernels import HIGHEST
 from .mvm import kron_dense, lk_mvm
 from .precond import pivoted_cholesky_grid, woodbury_preconditioner
 from .slq import slq_logdet
@@ -355,48 +357,52 @@ class CustomMVMEngine(IterativeEngine):
 def _pallas_mvm_raw(K1, K2, mask, u, noise):
     # Import at call time: repro.kernels imports repro.core.gp_kernels, so a
     # module-level import here would be circular. force_pallas=True runs the
-    # kernel even off-TPU (interpret mode) so the backend exercises the same
-    # code path everywhere.
+    # kernel on every backend: compiled by Mosaic on TPU, in interpret mode
+    # on the CPU (so tests exercise the same code path). f64 operands are
+    # cast to f32 at the kernel boundary and the result cast back.
     from ..kernels import ops
     return ops.lk_mvm_op(K1, K2, mask, u, noise, force_pallas=True)
 
 
-@jax.custom_vjp
-def _pallas_mvm(K1, K2, mask, u, noise):
-    """Differentiable wrapper: Pallas forward, analytic jnp cotangents.
+def _with_analytic_vjp(mvm_raw: Callable) -> Callable:
+    """Make a kernel MVM ``mvm_raw(K1, K2, mask, u, noise)`` differentiable.
 
     pallas_call has no autodiff rule, but the MVM is bilinear in (K1, K2, u),
-    so the VJPs are closed-form; the ``u`` cotangent is A(g) itself (A is
-    symmetric) and is routed back through the Pallas kernel.
+    so the VJPs are closed-form jnp einsums; the ``u`` cotangent is A(g)
+    itself (A is symmetric) and is routed back through ``mvm_raw``. Returns
+    ``mvm(K1, K2, mask, u, noise=0.0)``.
     """
-    return _pallas_mvm_raw(K1, K2, mask, u, noise)
+    @jax.custom_vjp
+    def mvm(K1, K2, mask, u, noise):
+        return mvm_raw(K1, K2, mask, u, noise)
+
+    def fwd(K1, K2, mask, u, noise):
+        return mvm_raw(K1, K2, mask, u, noise), (K1, K2, mask, u, noise)
+
+    def bwd(res, g):
+        K1, K2, mask, u, noise = res
+        n, m = mask.shape
+        gm = (g * mask).reshape(-1, n, m)   # flatten leading batch dims
+        um = (u * mask).reshape(-1, n, m)
+        umK2 = jnp.einsum("bnm,mk->bnk", um, K2, precision=HIGHEST)
+        dK1 = jnp.einsum("bik,bjk->ij", gm, umK2, precision=HIGHEST)
+        K1um = jnp.einsum("ij,bjm->bim", K1, um, precision=HIGHEST)
+        dK2 = jnp.einsum("bij,bik->jk", K1um, gm, precision=HIGHEST)
+        du = mvm_raw(K1, K2, mask, g, noise)          # A(g), A symmetric
+        dnoise = jnp.sum(gm * um).astype(jnp.asarray(noise).dtype)
+        return dK1, dK2, jnp.zeros_like(mask), du, dnoise
+
+    mvm.defvjp(fwd, bwd)
+
+    def mvm_kw(K1, K2, mask, u, noise=0.0):
+        # custom_vjp functions only take positional args; adapt to the
+        # ``mvm(K1, K2, mask, u, noise=...)`` calling convention.
+        return mvm(K1, K2, mask, u, noise)
+
+    return mvm_kw
 
 
-def _pallas_mvm_fwd(K1, K2, mask, u, noise):
-    return _pallas_mvm_raw(K1, K2, mask, u, noise), (K1, K2, mask, u, noise)
-
-
-def _pallas_mvm_bwd(res, g):
-    K1, K2, mask, u, noise = res
-    n, m = mask.shape
-    gm = (g * mask).reshape(-1, n, m)   # flatten leading batch dims
-    um = (u * mask).reshape(-1, n, m)
-    umK2 = jnp.einsum("bnm,mk->bnk", um, K2)
-    dK1 = jnp.einsum("bik,bjk->ij", gm, umK2)
-    K1um = jnp.einsum("ij,bjm->bim", K1, um)
-    dK2 = jnp.einsum("bij,bik->jk", K1um, gm)
-    du = _pallas_mvm_raw(K1, K2, mask, g, noise)          # A(g), A symmetric
-    dnoise = jnp.sum(gm * um).astype(jnp.asarray(noise).dtype)
-    return dK1, dK2, jnp.zeros_like(mask), du, dnoise
-
-
-_pallas_mvm.defvjp(_pallas_mvm_fwd, _pallas_mvm_bwd)
-
-
-def _pallas_mvm_kw(K1, K2, mask, u, noise=0.0):
-    # custom_vjp functions only take positional args; adapt to the
-    # ``mvm(K1, K2, mask, u, noise=...)`` calling convention.
-    return _pallas_mvm(K1, K2, mask, u, noise)
+_pallas_mvm_kw = _with_analytic_vjp(_pallas_mvm_raw)
 
 
 @register_engine("pallas")
@@ -451,22 +457,30 @@ class DistributedEngine(IterativeEngine):
                     f"{K1.dtype}")
             return None
         from ..analysis.vmem import best_fitting_blocks
-        n_local = max(K1.shape[0] // self.mesh.shape["data"], 1)
+        # The per-shard kernel sweeps all n rows of K1, so its blocks are
+        # judged at the global n (see lk_mvm_fused_rows).
+        n = K1.shape[1]
         m = jnp.asarray(K2).shape[0]
-        blocks = best_fitting_blocks(n_local, m, precision="f32",
-                                     out_itemsize=K1.dtype.itemsize)
+        blocks = best_fitting_blocks(n, m, precision="f32")
         if blocks is None and self.fused is True:
             raise ValueError(
                 "DistributedEngine(fused=True): no fused block size fits "
-                f"the per-shard VMEM budget for n_local={n_local}, m={m}")
+                f"the per-shard VMEM budget for n={n}, m={m}")
         return blocks
 
     def operator_from_grams(self, K1, K2, mask, noise):
         from ..distributed.lkgp_dist import dist_lk_mvm_fused, dist_lk_operator
         blocks = self._fused_blocks(K1, K2, mask)
         if blocks is not None:
-            base = dist_lk_mvm_fused(self.mesh, K1, K2, mask, noise,
-                                     block_n=blocks[0], block_m=blocks[1])
+            def fused_raw(K1, K2, mask, u, noise):
+                return dist_lk_mvm_fused(
+                    self.mesh, K1, K2, mask, noise, block_n=blocks[0],
+                    block_m=blocks[1])(u)
+
+            # Differentiable like the pallas engine's MVM: the fit's MLL
+            # gradient flows through the operator to K1, K2 and noise.
+            mvm = _with_analytic_vjp(fused_raw)
+            base = functools.partial(mvm, K1, K2, mask, noise=noise)
         else:
             base = dist_lk_operator(self.mesh, K1, K2, mask, noise)
 
@@ -504,7 +518,8 @@ def mll_cholesky(params: LKGPParams, X, t, Y, mask, t_kernel: str = "matern12",
     alpha = jax.scipy.linalg.cho_solve((L, True), y)
     N = jnp.sum(mask)
     logdet = 2.0 * jnp.sum(jnp.log(jnp.diag(L)))  # unobserved diag = 1 -> log 0
-    return -0.5 * jnp.dot(y, alpha) - 0.5 * logdet - 0.5 * N * _LOG_2PI
+    return (-0.5 * jnp.dot(y, alpha, precision=HIGHEST) - 0.5 * logdet
+            - 0.5 * N * _LOG_2PI)
 
 
 def make_mll(config: LKGPConfig, engine: "InferenceEngine") -> Callable:

@@ -10,9 +10,11 @@ transformed to their positive values by the caller.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
+    "HIGHEST",
     "sq_dist",
     "abs_dist",
     "rbf_ard",
@@ -21,6 +23,13 @@ __all__ = [
     "matern52",
     "KERNELS_1D",
 ]
+
+# Every f32 contraction in the GP numerics (Gram build, MVM, posterior
+# products, L-BFGS inner products) asks for full f32 precision. XLA's
+# default on TPU rounds f32 matmul operands to bf16 for one MXU pass, which
+# wrecks the distance expansion below (cancellation at ~3 digits) and
+# floors CG residuals near 1e-3. On the CPU the setting changes nothing.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def sq_dist(x1: jnp.ndarray, x2: jnp.ndarray) -> jnp.ndarray:
@@ -31,7 +40,7 @@ def sq_dist(x1: jnp.ndarray, x2: jnp.ndarray) -> jnp.ndarray:
     """
     n1 = jnp.sum(x1 * x1, axis=-1)[:, None]
     n2 = jnp.sum(x2 * x2, axis=-1)[None, :]
-    d2 = n1 + n2 - 2.0 * (x1 @ x2.T)
+    d2 = n1 + n2 - 2.0 * jnp.matmul(x1, x2.T, precision=HIGHEST)
     return jnp.maximum(d2, 0.0)
 
 

@@ -27,11 +27,27 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from .gp_kernels import HIGHEST
 from .mvm import lk_operator
 from .solvers import get_solver
 
 __all__ = ["sample_posterior_grid", "prior_residual_draws",
            "kronecker_correction"]
+
+
+def _psd_cholesky(K: jnp.ndarray, jitter: float) -> jnp.ndarray:
+    """Cholesky of a Gram matrix that is PSD in exact arithmetic.
+
+    The diagonal jitter is at least the rounding noise of K's dtype, n * eps
+    * max(diag K): smooth RBF Grams over thousands of configs have
+    eigenvalues far below f32 rounding, and an f32 Cholesky at the f64-sized
+    default jitter returns NaN. In f64 the floor is orders of magnitude
+    below the default, so f64 draws are unchanged.
+    """
+    n = K.shape[0]
+    floor = n * jnp.finfo(K.dtype).eps * jnp.max(jnp.diag(K))
+    eps = jnp.maximum(jnp.asarray(jitter, K.dtype), floor)
+    return jnp.linalg.cholesky(K + eps * jnp.eye(n, dtype=K.dtype))
 
 
 def prior_residual_draws(key, K1_joint: jnp.ndarray, K2: jnp.ndarray,
@@ -47,13 +63,13 @@ def prior_residual_draws(key, K1_joint: jnp.ndarray, K2: jnp.ndarray,
     dtype = K1_joint.dtype
     na = K1_joint.shape[0]
     m = K2.shape[0]
-    L1 = jnp.linalg.cholesky(K1_joint + jitter * jnp.eye(na, dtype=dtype))
-    L2 = jnp.linalg.cholesky(K2 + jitter * jnp.eye(m, dtype=dtype))
+    L1 = _psd_cholesky(K1_joint, jitter)
+    L2 = _psd_cholesky(K2, jitter)
 
     kz, ke = jax.random.split(key)
     Z = jax.random.normal(kz, (n_samples, na, m), dtype)
     # Prior samples on the joint grid: vec(F) ~ N(0, K1_joint (x) K2).
-    F = jnp.einsum("ij,sjm,km->sik", L1, Z, L2)
+    F = jnp.einsum("ij,sjm,km->sik", L1, Z, L2, precision=HIGHEST)
     eps = jnp.sqrt(noise) * jax.random.normal(ke, (n_samples, n_train, m),
                                               dtype)
     return F, eps
@@ -62,7 +78,8 @@ def prior_residual_draws(key, K1_joint: jnp.ndarray, K2: jnp.ndarray,
 def kronecker_correction(K1_joint: jnp.ndarray, u: jnp.ndarray,
                          K2: jnp.ndarray, n_train: int) -> jnp.ndarray:
     """Matheron correction (k1(., X) (x) k2(., t)) P^T u == K1[:, :n] @ u @ K2."""
-    return jnp.einsum("aj,sjm,mk->sak", K1_joint[:, :n_train], u, K2)
+    return jnp.einsum("aj,sjm,mk->sak", K1_joint[:, :n_train], u, K2,
+                      precision=HIGHEST)
 
 
 def sample_posterior_grid(key, K1_joint: jnp.ndarray, K2: jnp.ndarray,

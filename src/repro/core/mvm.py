@@ -29,6 +29,8 @@ from functools import partial
 
 import jax.numpy as jnp
 
+from .gp_kernels import HIGHEST
+
 __all__ = [
     "lk_mvm",
     "lk_operator",
@@ -48,8 +50,8 @@ def lk_mvm(K1: jnp.ndarray, K2: jnp.ndarray, mask: jnp.ndarray,
     symmetric-PSD on the full grid space, which the iterative solvers rely on.
     """
     um = u * mask
-    t = jnp.einsum("...nm,mk->...nk", um, K2)
-    s = jnp.einsum("ij,...jm->...im", K1, t)
+    t = jnp.einsum("...nm,mk->...nk", um, K2, precision=HIGHEST)
+    s = jnp.einsum("ij,...jm->...im", K1, t, precision=HIGHEST)
     return mask * s + noise * um
 
 

@@ -36,6 +36,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .gp_kernels import HIGHEST
+
 __all__ = ["PolishResult", "make_polish"]
 
 
@@ -58,7 +60,8 @@ def _two_loop(g, S, Yb, rho, valid):
     idx_new_to_old = jnp.arange(h - 1, -1, -1)
 
     def bwd(q, i):
-        a = jnp.where(valid[i], rho[i] * jnp.dot(S[i], q), 0.0)
+        a = jnp.where(valid[i], rho[i] * jnp.dot(S[i], q, precision=HIGHEST),
+                      0.0)
         return q - a * Yb[i], a
 
     q, alphas = jax.lax.scan(bwd, g, idx_new_to_old)
@@ -72,7 +75,8 @@ def _two_loop(g, S, Yb, rho, valid):
 
     def fwd(q, ia):
         i, a = ia
-        b = jnp.where(valid[i], rho[i] * jnp.dot(Yb[i], q), 0.0)
+        b = jnp.where(valid[i], rho[i] * jnp.dot(Yb[i], q, precision=HIGHEST),
+                      0.0)
         return q + (a - b) * S[i], None
 
     q, _ = jax.lax.scan(fwd, q, (idx_new_to_old[::-1], alphas[::-1]))
@@ -109,10 +113,10 @@ def make_polish(vg: Callable, steps: int, history: int = 5,
         def step(carry, _):
             x, f, g, S, Yb, rho, valid, n_acc = carry
             d = -_two_loop(g, S, Yb, rho, valid)
-            dg = jnp.dot(d, g)
+            dg = jnp.dot(d, g, precision=HIGHEST)
             descent = dg < 0
             d = jnp.where(descent, d, -g)
-            dg = jnp.where(descent, dg, -jnp.dot(g, g))
+            dg = jnp.where(descent, dg, -jnp.dot(g, g, precision=HIGHEST))
 
             cand = jax.lax.map(lambda a: vg(x + a * d, *args), alphas)
             fs, gs = cand
@@ -125,7 +129,7 @@ def make_polish(vg: Callable, steps: int, history: int = 5,
 
             s = x_new - x
             y = g_new - g
-            sy = jnp.dot(s, y)
+            sy = jnp.dot(s, y, precision=HIGHEST)
             good = any_ok & (sy > 1e-10 * jnp.linalg.norm(s)
                              * jnp.linalg.norm(y))
             rho_new = jnp.where(good, 1.0 / jnp.where(good, sy, 1.0), 0.0)

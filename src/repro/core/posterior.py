@@ -181,7 +181,8 @@ class Posterior:
         """Exact posterior mean over the grid: (n(+n*), m), y units."""
         K1a, K2 = self._grams
         n = self._state.n
-        mean_t = jnp.einsum("aj,jm,mk->ak", K1a[:, :n], self.alpha, K2)
+        mean_t = jnp.einsum("aj,jm,mk->ak", K1a[:, :n], self.alpha, K2,
+                            precision=gk.HIGHEST)
         return self._state.y_tf.inverse(mean_t)
 
     def samples(self, key, n_samples: int | None = None) -> jnp.ndarray:
@@ -395,7 +396,7 @@ def _batched_cov_fn(t_kernel: str, jitter: float):
         ag = alpha.reshape(n, m)
         tmp = jnp.sum(ag[:, :, None] * K2[None, :, :], axis=1)
         mean_t = jnp.sum(K1[:, :, None] * tmp[None, :, :], axis=1)
-        C = Kfull - Kx @ S
+        C = Kfull - jnp.matmul(Kx, S, precision=gk.HIGHEST)
         var_grid = jnp.maximum(jnp.diag(C), 0.0).reshape(n, m)
         Lc = jnp.linalg.cholesky(
             C + 10.0 * jitter * jnp.eye(N, dtype=C.dtype))
@@ -481,7 +482,7 @@ class BatchedPosterior:
         B, n, m = st.Y.shape
         z = jax.random.normal(key, (B, n_samples, n * m), mean_t.dtype)
         draws = mean_t.reshape(B, 1, n * m) + jnp.einsum(
-            "bij,bsj->bsi", Lc, z)
+            "bij,bsj->bsi", Lc, z, precision=gk.HIGHEST)
         raw = draws.reshape(B, n_samples, n, m).transpose(1, 0, 2, 3)
         return raw * scale[None, :, None, None] \
             + shift[None, :, None, None]

@@ -27,6 +27,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .gp_kernels import HIGHEST
+
 __all__ = ["pivoted_cholesky_latent", "pivoted_cholesky_grid",
            "woodbury_preconditioner"]
 
@@ -128,15 +130,16 @@ def woodbury_preconditioner(L, noise):
 
     N, r = L.shape
     eye = jnp.eye(r, dtype=L.dtype)
-    inner = noise * eye + L.T @ L            # (r, r), SPD
+    inner = noise * eye + jnp.matmul(L.T, L, precision=HIGHEST)  # SPD
     chol = jnp.linalg.cholesky(inner)
 
     def apply(v):
-        w = jnp.einsum("nr,...n->...r", L, v)
+        w = jnp.einsum("nr,...n->...r", L, v, precision=HIGHEST)
         # cho_solve wants matching batch dims; fold leading dims into the
         # column axis instead so one (r, r) factor serves every RHS.
         wf = w.reshape(-1, r)
         z = jax.scipy.linalg.cho_solve((chol, True), wf.T).T.reshape(w.shape)
-        return v / noise - jnp.einsum("nr,...r->...n", L, z) / noise
+        return v / noise - jnp.einsum("nr,...r->...n", L, z,
+                                      precision=HIGHEST) / noise
 
     return apply

@@ -13,6 +13,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from .gp_kernels import HIGHEST
+
 __all__ = ["lanczos", "slq_logdet", "slq_logdet_from_tridiag",
            "tridiag_from_cg", "rademacher_probes"]
 
@@ -48,8 +50,8 @@ def lanczos(A: Callable, v0: jnp.ndarray, num_iters: int):
         alpha = dot(w, v)
         w = w - alpha[..., None, None] * v
         # Full reorthogonalisation: w -= V V^T w (masked basis, so stays in S).
-        coeffs = jnp.einsum("kpnm,pnm->kp", V, w)
-        w = w - jnp.einsum("kp,kpnm->pnm", coeffs, V)
+        coeffs = jnp.einsum("kpnm,pnm->kp", V, w, precision=HIGHEST)
+        w = w - jnp.einsum("kp,kpnm->pnm", coeffs, V, precision=HIGHEST)
         beta = jnp.sqrt(jnp.maximum(dot(w, w), 0.0))
         v_next = jnp.where(beta[..., None, None] > 1e-12,
                            w / jnp.maximum(beta[..., None, None], 1e-30), 0.0)

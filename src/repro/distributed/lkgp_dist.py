@@ -22,7 +22,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ..compat import shard_map
 
 from ..core.gp_kernels import KERNELS_1D, rbf_ard
 
@@ -47,7 +46,7 @@ def dist_lk_operator(mesh: Mesh, K1_rows, K2, mask, noise):
         s_loc = k1r @ t_full                          # (n/p, m)
         return msk * s_loc + noise * (msk * u)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data", None), P(None, None), P("data", None),
                   P("data", None)),
@@ -88,7 +87,7 @@ def dist_lk_mvm_fused(mesh: Mesh, K1_rows, K2, mask, noise, *,
                                  block_n=block_n, block_m=block_m,
                                  precision=precision, interpret=interpret)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data", None), P(None, None), P("data", None),
                   P("data", None)),
@@ -142,7 +141,7 @@ def dist_mll_value(mesh: Mesh, params_ls, params_tls, params_os, params_noise,
         x_full = jax.lax.all_gather(x_same, "data", axis=0, tiled=True)
         return rbf_ard(x_loc, x_full, params_ls)
 
-    k1_rows = shard_map(
+    k1_rows = jax.shard_map(
         build_k1_rows, mesh=mesh,
         in_specs=(P("data", None), P("data", None)),
         out_specs=P("data", None), check_vma=False)(X, X)

@@ -9,7 +9,9 @@ from a small sweep over ``CANDIDATE_BLOCKS`` ({64, 128, 256}):
 * **timed mode** (default on TPU, or ``timed=True``): each candidate is
   compiled and timed on a synthetic problem of the bucketed shape,
   validated against the :mod:`repro.kernels.ref` oracle, and the fastest
-  valid candidate wins.
+  candidate wins. A candidate the compiler refuses, or one that disagrees
+  with the oracle, is an error naming the shape and blocks — never
+  skipped.
 * **heuristic mode** (default off-TPU, and always under ``jit`` tracing —
   timing inside a trace is meaningless): the smallest candidate covering
   each axis, i.e. the analytic single-sweep optimum.
@@ -27,7 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..analysis.vmem import best_fitting_blocks, fused_vmem_breakdown
+from ..analysis.vmem import (best_fitting_blocks, effective_blocks,
+                             fused_vmem_breakdown)
 
 __all__ = ["CANDIDATE_BLOCKS", "autotune_blocks", "clear_cache",
            "cache_contents"]
@@ -82,7 +85,7 @@ def _candidate_pairs(n: int, m: int, precision: str = "f32"):
     seen, pairs = set(), []
     for bn in CANDIDATE_BLOCKS:
         for bm in CANDIDATE_BLOCKS:
-            eff = (min(bn, _bucket(max(8, n))), min(bm, _bucket(max(8, m))))
+            eff = effective_blocks(n, m, bn, bm, precision)[:2]
             if eff in seen:
                 continue
             seen.add(eff)
@@ -110,10 +113,12 @@ def autotune_blocks(n: int, m: int, B: int = 1, *, precision: str = "f32",
     """Pick (block_n, block_m) for the fused kernel at shape (B, n, m).
 
     ``timed=None`` resolves to True on TPU and False elsewhere. Timed
-    sweeps validate every candidate against the jnp oracle and skip any
-    that fail; a fully-failing sweep falls back to the heuristic. Safe to
-    call at ``jit`` trace time with ``timed=False`` (pure-python cache
-    lookup / heuristic — no compilation, no timing).
+    sweeps validate every candidate against the jnp oracle; a candidate
+    that fails to compile or to match raises ``RuntimeError`` naming
+    (n, m, B, block_n, block_m) — the heuristic is never a silent
+    substitute for a sweep. Safe to call at ``jit`` trace time with
+    ``timed=False`` (pure-python cache lookup / heuristic — no
+    compilation, no timing).
 
     Every candidate considered (timed or heuristic) is pre-filtered
     against the exact VMEM budget model (:mod:`repro.analysis.vmem`).
@@ -147,7 +152,10 @@ def autotune_blocks(n: int, m: int, B: int = 1, *, precision: str = "f32",
     K2 = C @ C.T / mb + 0.5 * jnp.eye(mb, dtype=jnp.float32)
     mask = jnp.ones((nb, mb), jnp.float32)
     u = jax.random.normal(k3, (Bb, nb, mb), jnp.float32)
-    ref = np.asarray(lk_mvm_ref(K1, K2, mask, u, 0.1))
+    # The oracle at full f32 precision: XLA's default on TPU contracts f32
+    # in one bf16 pass, far coarser than the kernel's f32 mode.
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(lk_mvm_ref(K1, K2, mask, u, 0.1))
     scale = max(1.0, float(np.max(np.abs(ref))))
 
     best, best_t = None, float("inf")
@@ -156,19 +164,22 @@ def autotune_blocks(n: int, m: int, B: int = 1, *, precision: str = "f32",
             return lk_mvm_fused(K1, K2, mask, u, 0.1, block_n=_bn,
                                 block_m=_bm, precision=precision,
                                 interpret=interpret)
+        where = (f"lk_mvm_fused candidate (n={nb}, m={mb}, B={Bb}, "
+                 f"block_n={bn}, block_m={bm}, {precision})")
         try:
             # Correctness screen of each candidate against the dense
             # reference needs the values on host.
             out = np.asarray(run(K1, K2, mask, u))  # lint: disable=RA103
-        except Exception:
-            continue
+        except jax.errors.JaxRuntimeError as e:
+            raise RuntimeError(f"{where} was refused by the compiler after "
+                               "passing the VMEM filter") from e
         tol = atol * scale if precision == "f32" else 0.1 * scale
         if not np.allclose(out, ref, atol=tol):
-            continue
+            err = np.max(np.abs(out - ref))
+            raise RuntimeError(f"{where} disagrees with the jnp oracle "
+                               f"(max abs error {err:.3g})")
         t = _time_candidate(run, (K1, K2, mask, u))
         if t < best_t:
             best, best_t = (bn, bm), t
-    if best is None:
-        best = _heuristic(n, m, precision)
     _CACHE[key] = best
     return best

@@ -11,19 +11,20 @@ Two implementations live here:
 :func:`lk_mvm_fused` (the default behind :func:`lk_mvm_pallas`)
     ONE ``pallas_call``. Grid (B, n-rows, m-cols) with an inner K1-row
     sweep; each step recomputes the per-block-row tile
-    ``T = (mask * U)[k, :] @ K2[:, j]`` straight into VMEM scratch and
+    ``T = (mask * U)[k, :] @ K2[:, j]`` straight into VMEM and
     accumulates ``K1[i, k] @ T`` — the (B, n, m) f32 intermediate NEVER
-    touches HBM. The noise/mask epilogue tiles are sliced out of the
-    already-resident row strips when the sweep passes k == i, so the fused
-    kernel reads each operand exactly once per grid step. The recompute
-    factor on the cheap first product is n/block_n on its O(n m^2) term —
-    for learning-curve grids (m << n, m <~ block) this is bounded by the
+    touches HBM. The masked input ``mask * U`` is formed once by the
+    wrapper (fused by XLA into the padding copy); the noise/mask epilogue
+    reads its own (i, j) tiles of mask and U. The recompute factor on the
+    cheap first product is n/block_n on its O(n m^2) term — for
+    learning-curve grids (m << n, m <~ block) this is bounded by the
     O(n^2 m) second product, while HBM traffic drops by the full
     intermediate round-trip. Supports a bf16-inputs / f32-accumulate mode
     (``precision="bf16"``); block sizes come from
-    :mod:`repro.kernels.autotune` when not given explicitly.
-    VMEM per step is O(block_n * m + m * block_m), so the fused kernel
-    targets the paper's regime m <~ 4096.
+    :mod:`repro.kernels.autotune` when not given explicitly. VMEM per step
+    is O(block_n * m + m * block_m), so the fused kernel targets the
+    paper's regime m <~ 4096. :func:`lk_mvm_fused_rows` runs the same
+    kernel on one row shard (the distributed engine's per-shard body).
 
 :func:`lk_mvm_two_stage` (the committed baseline the benchmarks gate
     against) — two ``pallas_call``s with the masked intermediate
@@ -35,6 +36,13 @@ Two implementations live here:
 
 Accumulation always runs over the innermost grid axis into an f32 VMEM
 scratch; epilogues apply the mask and noise term on the final step.
+
+Mosaic constraints the wrappers honour: every operand reaching a
+``pallas_call`` is f32 or bf16 (f64 callers are cast at the boundary and
+the result cast back — the kernels accumulate in f32 either way), every
+block index is int32 (a bare ``0`` is int64 under ``jax_enable_x64``), and
+block edges follow :func:`repro.analysis.vmem.effective_blocks` (whole
+axis, or a multiple of the 128-lane tile).
 """
 from __future__ import annotations
 
@@ -45,10 +53,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..analysis.vmem import check_fused_blocks
+from ..analysis.vmem import block_edge, check_fused_blocks, effective_blocks
 
 __all__ = ["lk_mvm_pallas", "lk_mvm_fused", "lk_mvm_fused_rows",
            "lk_mvm_two_stage"]
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Interpret mode only where Mosaic cannot run: on the CPU backend."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret
+
+
+def _zero():
+    """Block index 0 as int32: Mosaic refuses the int64 a bare 0 becomes
+    under ``jax_enable_x64``."""
+    return jnp.int32(0)
+
+
+def smem_scalar_spec():
+    """BlockSpec of a (1, 1) f32 scalar in SMEM (int32 index map)."""
+    return pl.BlockSpec((1, 1), lambda *_: (_zero(), _zero()),
+                        memory_space=pltpu.SMEM)
+
+
+def _compute_dtype(precision: str):
+    if precision not in ("f32", "bf16"):
+        raise ValueError(f"precision must be 'f32' or 'bf16', got {precision!r}")
+    return jnp.bfloat16 if precision == "bf16" else jnp.float32
+
+
+def _dot(a, b):
+    """MXU product with f32 accumulation; f32 operands at full f32
+    precision (Mosaic's default contraction for f32 is not)."""
+    prec = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot(a, b, precision=prec,
+                       preferred_element_type=jnp.float32)
 
 
 def _stage_right_kernel(u_ref, mask_ref, k2_ref, o_ref, acc_ref, *, nk: int):
@@ -59,9 +101,7 @@ def _stage_right_kernel(u_ref, mask_ref, k2_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    um = (u_ref[0] * mask_ref[...]).astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(um, k2_ref[...].astype(jnp.float32),
-                                preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(u_ref[0] * mask_ref[...], k2_ref[...])
 
     @pl.when(k == nk - 1)
     def _done():
@@ -77,54 +117,13 @@ def _stage_left_kernel(k1_ref, t_ref, mask_ref, u_ref, noise_ref, o_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot(k1_ref[...].astype(jnp.float32),
-                                t_ref[0].astype(jnp.float32),
-                                preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(k1_ref[...], t_ref[0])
 
     @pl.when(k == nk - 1)
     def _done():
         mask = mask_ref[...]
         noise = noise_ref[0, 0]
-        out = mask * acc_ref[...] + noise * (mask * u_ref[0].astype(jnp.float32))
-        o_ref[0] = out.astype(o_ref.dtype)
-
-
-def _fused_kernel(k1_ref, u_ref, mask_ref, k2_ref, noise_ref, o_ref,
-                  acc_ref, epi_mask_ref, epi_u_ref, *, nk: int, bm: int,
-                  compute_dtype):
-    """Single-pass out[b, i, j] = mask*(sum_k K1[i,k] @ ((mask*U)[k,:]@K2[:,j]))
-    + noise * mask * U, with T tiles living only in VMEM."""
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # Stage-R tile for block-row k, computed straight into registers/VMEM:
-    # (bn, m) x (m, bm) — the full m sweep in one MXU pass.
-    um = (u_ref[0] * mask_ref[...]).astype(compute_dtype)
-    t = jax.lax.dot(um, k2_ref[...].astype(compute_dtype),
-                    preferred_element_type=jnp.float32)
-    acc_ref[...] += jax.lax.dot(k1_ref[...].astype(compute_dtype),
-                                t.astype(compute_dtype),
-                                preferred_element_type=jnp.float32)
-
-    # The epilogue needs mask/U at block (i, j); the k-sweep's row strips
-    # contain exactly those tiles when k == i — slice them out of VMEM
-    # instead of fetching them from HBM again.
-    @pl.when(k == i)
-    def _capture():
-        off = pl.multiple_of(j * bm, bm)
-        epi_mask_ref[...] = mask_ref[:, pl.ds(off, bm)].astype(jnp.float32)
-        epi_u_ref[...] = u_ref[0, :, pl.ds(off, bm)].astype(jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _done():
-        msk = epi_mask_ref[...]
-        noise = noise_ref[0, 0]
-        out = msk * acc_ref[...] + noise * (msk * epi_u_ref[...])
+        out = mask * acc_ref[...] + noise * (mask * u_ref[0])
         o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -147,19 +146,19 @@ def lk_mvm_two_stage(K1: jnp.ndarray, K2: jnp.ndarray, mask: jnp.ndarray,
     rows/cols of mask are zero, K2/K1 padding contributes zero partial
     products.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n, m = mask.shape
     batch_shape = u.shape[:-2]
-    u3 = u.reshape((-1, n, m))
-    B = u3.shape[0]
     dtype = u.dtype
+    f32 = lambda x: jnp.asarray(x).astype(jnp.float32)  # noqa: E731
+    u3 = f32(u).reshape((-1, n, m))
+    B = u3.shape[0]
 
-    bn = min(block_n, max(8, n))
-    bm = min(block_m, max(8, m))
-    K1p = _pad_to(K1, (bn, bn))
-    K2p = _pad_to(K2, (bm, bm))
-    maskp = _pad_to(mask, (bn, bm))
+    bn = block_edge(block_n, n)
+    bm = block_edge(block_m, m)
+    K1p = _pad_to(f32(K1), (bn, bn))
+    K2p = _pad_to(f32(K2), (bm, bm))
+    maskp = _pad_to(f32(mask), (bn, bm))
     up = _pad_to(u3, (1, bn, bm))
     npad, mpad = maskp.shape
     noise_arr = jnp.asarray(noise, jnp.float32).reshape(1, 1)
@@ -190,15 +189,95 @@ def lk_mvm_two_stage(K1: jnp.ndarray, K2: jnp.ndarray, mask: jnp.ndarray,
             pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, k, j)),   # T
             pl.BlockSpec((bn, bm), lambda b, i, j, k: (i, j)),         # mask
             pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, i, j)),   # U
-            pl.BlockSpec(memory_space=pltpu.SMEM),                     # noise
+            smem_scalar_spec(),                                        # noise
         ],
         out_specs=pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, npad, mpad), dtype),
+        out_shape=jax.ShapeDtypeStruct((B, npad, mpad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, bm), jnp.float32)],
         interpret=interpret,
     )(K1p, t, maskp, up, noise_arr)
 
-    return out[:, :n, :m].reshape(*batch_shape, n, m)
+    return out[:, :n, :m].reshape(*batch_shape, n, m).astype(dtype)
+
+
+def _fused_kernel(k1_ref, um_ref, k2_ref, mask_ref, u_ref, noise_ref, o_ref,
+                  acc_ref, *, nk: int):
+    """out[b, i, j] = mask[i, j] * (sum_k K1[i, k] @ (um[b, k, :] @ K2[:, j]))
+    + noise * mask[i, j] * u[b, i, j], with T tiles living only in VMEM.
+
+    ``um = mask * u`` over all rows of the K1 sweep (k indexes GLOBAL
+    block rows); the epilogue's mask/u tiles are dedicated inputs at the
+    output block (i, j), so the same kernel serves the square MVM and one
+    row shard of it (i indexes the shard's local rows).
+    """
+    k = pl.program_id(3)
+
+    @pl.when(k == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # Stage-R tile for block-row k: (bn, m) x (m, bm) — the full m sweep
+    # in one MXU pass, straight into VMEM.
+    t = _dot(um_ref[0], k2_ref[...])
+    acc_ref[...] += _dot(k1_ref[...], t.astype(um_ref.dtype))
+
+    @pl.when(k == nk - 1)
+    def _done():
+        msk = mask_ref[...].astype(jnp.float32)
+        noise = noise_ref[0, 0]
+        o_ref[0] = msk * acc_ref[...] + noise * (
+            msk * u_ref[0].astype(jnp.float32))
+
+
+def _fused_call(K1_rows, K2, mask_rows, u_rows, um, noise, *, block_n: int,
+                block_m: int, precision: str, interpret: bool | None):
+    """One fused ``pallas_call``: rows (B, n_local, m) of the masked MVM.
+
+    K1_rows: (n_local, n); K2: (m, m); mask_rows: (n_local, m); u_rows:
+    (B, n_local, m) feed the epilogue; um: (B, n, m) is ``mask * u`` over
+    every row of the K1 sweep. Returns f32 (B, n_local, m).
+    """
+    interpret = resolve_interpret(interpret)
+    compute_dtype = _compute_dtype(precision)
+    n_local, m = mask_rows.shape
+    n = um.shape[-2]
+    B = u_rows.shape[0]
+
+    # Static VMEM guard (trace time, shapes only): an oversized block
+    # choice fails here with an actionable message instead of at Mosaic
+    # compile time on TPU — or worse, "working" in interpret mode on CPU
+    # and OOMing the first time the same trace reaches hardware.
+    check_fused_blocks(n, m, block_n, block_m, precision)
+    bn, bm, _ = effective_blocks(n, m, block_n, block_m, precision)
+    cast = lambda x: jnp.asarray(x).astype(compute_dtype)  # noqa: E731
+    K1p = _pad_to(cast(K1_rows), (bn, bn))
+    K2p = _pad_to(cast(K2), (bm, bm))
+    maskp = _pad_to(cast(mask_rows), (bn, bm))    # exact in bf16: 0/1
+    up = _pad_to(cast(u_rows), (1, bn, bm))
+    ump = _pad_to(cast(um), (1, bn, bm))
+    nlpad, mpad = maskp.shape
+    npad = ump.shape[1]       # K1 cols and um rows: n padded to bn alike
+    noise_arr = jnp.asarray(noise, jnp.float32).reshape(1, 1)
+
+    gi, gj, gk = nlpad // bn, mpad // bm, npad // bn
+    out = pl.pallas_call(
+        functools.partial(_fused_kernel, nk=gk),
+        grid=(B, gi, gj, gk),
+        in_specs=[
+            pl.BlockSpec((bn, bn), lambda b, i, j, k: (i, k)),            # K1
+            pl.BlockSpec((1, bn, mpad),
+                         lambda b, i, j, k: (b, k, _zero())),             # um strip
+            pl.BlockSpec((mpad, bm), lambda b, i, j, k: (_zero(), j)),    # K2 strip
+            pl.BlockSpec((bn, bm), lambda b, i, j, k: (i, j)),            # mask
+            pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, i, j)),      # u
+            smem_scalar_spec(),                                           # noise
+        ],
+        out_specs=pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, i, j)),
+        out_shape=jax.ShapeDtypeStruct((B, nlpad, mpad), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bn, bm), jnp.float32)],   # accumulator
+        interpret=interpret,
+    )(K1p, ump, K2p, maskp, up, noise_arr)
+    return out[:, :n_local, :m]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m",
@@ -209,104 +288,19 @@ def lk_mvm_fused(K1: jnp.ndarray, K2: jnp.ndarray, mask: jnp.ndarray,
                  interpret: bool | None = None) -> jnp.ndarray:
     """Single-pass masked Kronecker MVM. u: (..., n, m) -> same shape.
 
-    One ``pallas_call``; the stage-R tile stays in VMEM scratch (see module
+    One ``pallas_call``; the stage-R tile stays in VMEM (see module
     docstring). ``precision="bf16"`` casts the matmul inputs to bfloat16
     and accumulates in f32 (the mask/noise epilogue stays f32); the output
     keeps u's dtype. Zero-padding to block multiples is harmless: padded
     rows/cols of mask are zero, K2/K1 padding contributes zero partial
     products.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if precision not in ("f32", "bf16"):
-        raise ValueError(f"precision must be 'f32' or 'bf16', got {precision!r}")
-    compute_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
     n, m = mask.shape
-    batch_shape = u.shape[:-2]
     u3 = u.reshape((-1, n, m))
-    B = u3.shape[0]
-    dtype = u.dtype
-
-    min_edge = 16 if precision == "bf16" else 8
-    bn = min(block_n, max(min_edge, n))
-    bm = min(block_m, max(min_edge, m))
-    # Static VMEM guard (trace time, shapes only): an oversized block
-    # choice fails here with an actionable message instead of at Mosaic
-    # compile time on TPU — or worse, "working" in interpret mode on CPU
-    # and OOMing the first time the same trace reaches hardware.
-    check_fused_blocks(n, m, block_n, block_m, precision,
-                       out_itemsize=jnp.dtype(dtype).itemsize)
-    if precision == "bf16":
-        K1 = K1.astype(jnp.bfloat16)
-        K2 = K2.astype(jnp.bfloat16)
-        u3 = u3.astype(jnp.bfloat16)
-        mask = mask.astype(jnp.bfloat16)   # exact: mask is 0/1
-    K1p = _pad_to(K1, (bn, bn))
-    K2p = _pad_to(K2, (bm, bm))
-    maskp = _pad_to(mask, (bn, bm))
-    up = _pad_to(u3, (1, bn, bm))
-    npad, mpad = maskp.shape
-    noise_arr = jnp.asarray(noise, jnp.float32).reshape(1, 1)
-
-    gn, gm, gkn = npad // bn, mpad // bm, npad // bn
-
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, nk=gkn, bm=bm,
-                          compute_dtype=compute_dtype),
-        grid=(B, gn, gm, gkn),
-        in_specs=[
-            pl.BlockSpec((bn, bn), lambda b, i, j, k: (i, k)),       # K1
-            pl.BlockSpec((1, bn, mpad), lambda b, i, j, k: (b, k, 0)),  # U row strip
-            pl.BlockSpec((bn, mpad), lambda b, i, j, k: (k, 0)),     # mask row strip
-            pl.BlockSpec((mpad, bm), lambda b, i, j, k: (0, j)),     # K2 col strip
-            pl.BlockSpec(memory_space=pltpu.SMEM),                   # noise
-        ],
-        out_specs=pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, npad, mpad), dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bn, bm), jnp.float32),   # accumulator
-            pltpu.VMEM((bn, bm), jnp.float32),   # epilogue mask tile
-            pltpu.VMEM((bn, bm), jnp.float32),   # epilogue U tile
-        ],
-        interpret=interpret,
-    )(K1p, up, maskp, K2p, noise_arr)
-
-    return out[:, :n, :m].reshape(*batch_shape, n, m)
-
-
-def _fused_rows_kernel(k1_ref, um_ref, k2_ref, mask_ref, u_ref, noise_ref,
-                       o_ref, acc_ref, *, nk: int, compute_dtype):
-    """Rectangular fused pass for one row shard:
-    out[i, j] = mask_rows * (sum_k K1_rows[i, k] @ (um_full[k, :] @ K2[:, j]))
-    + noise * mask_rows * u_rows.
-
-    Unlike :func:`_fused_kernel`, the epilogue mask/u tiles are dedicated
-    inputs indexed at the *local* output block (i, j): under row sharding
-    the square kernel's ``k == i`` capture trick is invalid, because the
-    k sweep runs over GLOBAL block rows while i indexes the shard's local
-    rows — the strips never align except on shard 0.
-    """
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # Stage-R tile for global block-row k (um_full is pre-masked by the
-    # caller: mask*u gathered across shards), straight into VMEM.
-    t = jax.lax.dot(um_ref[...].astype(compute_dtype),
-                    k2_ref[...].astype(compute_dtype),
-                    preferred_element_type=jnp.float32)
-    acc_ref[...] += jax.lax.dot(k1_ref[...].astype(compute_dtype),
-                                t.astype(compute_dtype),
-                                preferred_element_type=jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _done():
-        msk = mask_ref[...].astype(jnp.float32)
-        noise = noise_ref[0, 0]
-        out = msk * acc_ref[...] + noise * (msk * u_ref[...].astype(jnp.float32))
-        o_ref[...] = out.astype(o_ref.dtype)
+    out = _fused_call(K1, K2, mask, u3, mask * u3, noise, block_n=block_n,
+                      block_m=block_m, precision=precision,
+                      interpret=interpret)
+    return out.reshape(u.shape).astype(u.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m",
@@ -321,7 +315,7 @@ def lk_mvm_fused_rows(K1_rows: jnp.ndarray, K2: jnp.ndarray,
     This is the per-shard body of the distributed fused path (see
     :func:`repro.distributed.lkgp_dist.dist_lk_mvm_fused`): the caller
     all-gathers ``um_full = mask * u`` (n, m) once per MVM and every shard
-    runs this kernel on its local row block.
+    runs the fused kernel on its local row block.
 
     K1_rows: (n_local, n) local row block of K1; mask_rows / u_rows:
     (n_local, m) local rows of mask / u; um_full: (n, m) gathered masked
@@ -329,64 +323,13 @@ def lk_mvm_fused_rows(K1_rows: jnp.ndarray, K2: jnp.ndarray,
     ``mask_rows * (K1_rows @ (um_full @ K2)) + noise * (mask_rows * u_rows)``.
 
     Rank-2 only (the shard_map body is rank-2; engines lax.map the batch).
-    Zero-padding to block multiples is harmless for the same reason as in
-    :func:`lk_mvm_fused`.
+    Block sizes are judged against the global n, the length of the K1
+    sweep.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if precision not in ("f32", "bf16"):
-        raise ValueError(f"precision must be 'f32' or 'bf16', got {precision!r}")
-    compute_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
-    n_local, m = mask_rows.shape
-    n = um_full.shape[0]
-    dtype = u_rows.dtype
-
-    min_edge = 16 if precision == "bf16" else 8
-    bn = min(block_n, max(min_edge, n_local))
-    bm = min(block_m, max(min_edge, m))
-    # Per-shard VMEM guard. The square kernel's byte model upper-bounds this
-    # variant: it charges two (bn, mpad) row strips + 3 scratch tiles where
-    # this kernel holds one (bn, mpad) strip, two (bn, bm) epilogue tiles
-    # and 1 scratch tile.
-    check_fused_blocks(n_local, m, block_n, block_m, precision,
-                       out_itemsize=jnp.dtype(dtype).itemsize)
-    if precision == "bf16":
-        K1_rows = K1_rows.astype(jnp.bfloat16)
-        K2 = K2.astype(jnp.bfloat16)
-        um_full = um_full.astype(jnp.bfloat16)
-        mask_rows = mask_rows.astype(jnp.bfloat16)   # exact: mask is 0/1
-        u_rows = u_rows.astype(jnp.bfloat16)
-    K1p = _pad_to(K1_rows, (bn, bn))
-    K2p = _pad_to(K2, (bm, bm))
-    maskp = _pad_to(mask_rows, (bn, bm))
-    urp = _pad_to(u_rows, (bn, bm))
-    ump = _pad_to(um_full, (bn, bm))
-    nlpad, mpad = maskp.shape
-    # K1 cols and um_full rows are both n padded to the same bn multiple.
-    npad = ump.shape[0]
-    noise_arr = jnp.asarray(noise, jnp.float32).reshape(1, 1)
-
-    gi, gj, gk = nlpad // bn, mpad // bm, npad // bn
-
-    out = pl.pallas_call(
-        functools.partial(_fused_rows_kernel, nk=gk,
-                          compute_dtype=compute_dtype),
-        grid=(gi, gj, gk),
-        in_specs=[
-            pl.BlockSpec((bn, bn), lambda i, j, k: (i, k)),      # K1 rows
-            pl.BlockSpec((bn, mpad), lambda i, j, k: (k, 0)),    # um row strip
-            pl.BlockSpec((mpad, bm), lambda i, j, k: (0, j)),    # K2 col strip
-            pl.BlockSpec((bn, bm), lambda i, j, k: (i, j)),      # local mask
-            pl.BlockSpec((bn, bm), lambda i, j, k: (i, j)),      # local u
-            pl.BlockSpec(memory_space=pltpu.SMEM),               # noise
-        ],
-        out_specs=pl.BlockSpec((bn, bm), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((nlpad, mpad), dtype),
-        scratch_shapes=[pltpu.VMEM((bn, bm), jnp.float32)],
-        interpret=interpret,
-    )(K1p, ump, K2p, maskp, urp, noise_arr)
-
-    return out[:n_local, :m]
+    out = _fused_call(K1_rows, K2, mask_rows, u_rows[None], um_full[None],
+                      noise, block_n=block_n, block_m=block_m,
+                      precision=precision, interpret=interpret)
+    return out[0].astype(u_rows.dtype)
 
 
 def lk_mvm_pallas(K1, K2, mask, u, noise=0.0, *, block_n: int = 128,

@@ -1,8 +1,8 @@
 """Jitted public wrappers that dispatch Pallas kernels or jnp oracles.
 
-On TPU the Pallas path is used; elsewhere (this container is CPU-only) the
-default is the jnp oracle, with ``force_pallas=True`` running the kernels in
-interpret mode for validation. The MVM routes through the single-pass fused
+On TPU the Pallas path is used; on other backends the default is the jnp
+oracle, with ``force_pallas=True`` running the kernels anyway (in interpret
+mode on the CPU, for validation). The MVM routes through the single-pass fused
 kernel by default (``fused=False`` selects the two-stage baseline); block
 sizes come from the :mod:`repro.kernels.autotune` cache when not given.
 """

@@ -16,10 +16,8 @@ __all__ = ["make_production_mesh", "make_debug_mesh"]
 
 
 def _make_mesh(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):  # axis_types landed after 0.4.x
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

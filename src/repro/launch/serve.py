@@ -25,6 +25,7 @@ import numpy as np
 from ..configs import get_config, get_smoke_config
 from ..models import build_model
 from ..train.trainer import make_serve_steps
+from .compile_cache import use_compile_cache
 from .train import make_mesh_from_args
 
 
@@ -101,6 +102,7 @@ def main(argv=None):
     ap.add_argument("--refit-every", type=int, default=4)
     ap.add_argument("--lbfgs-iters", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.service == "curves":
         return main_curves(args)

@@ -23,7 +23,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ..compat import shard_map
 
 __all__ = ["moe_param_table", "moe_ffn", "moe_ffn_sharded", "moe_capacity"]
 
@@ -182,7 +181,7 @@ def moe_ffn_sharded(x, params, cfg, mesh) -> jnp.ndarray:
         else ()
     xspec = P(dp_ok if dp_ok else None, None, None)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda xl, r, a, b, c: _local_moe(xl, r, a, b, c, cfg,
                                           cfg.num_experts // tp),
         mesh=mesh,
@@ -263,7 +262,7 @@ def moe_ffn_sharded_decode(x, params, cfg, mesh) -> jnp.ndarray:
     dp = tuple(a for a in ("pod", "data") if a in mesh.shape
                and x.shape[0] % mesh.shape[a] == 0)
     xspec = P(dp if dp else None, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda xl, r, a, b, c: _local_moe_tokens_gathered(
             xl, r, a, b, c, cfg, cfg.num_experts // tp, dp),
         mesh=mesh,

@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ..compat import shard_map
 
 __all__ = ["quantize_leaf", "dequantize_leaf", "compressed_psum_tree",
            "make_compressed_allreduce"]
@@ -78,8 +77,8 @@ def make_compressed_allreduce(mesh: Mesh):
     def wrapped(grads, errors):
         specs = jax.tree_util.tree_map(lambda _: P(), grads)
         espec = jax.tree_util.tree_map(lambda _: P(), errors)
-        return shard_map(fn, mesh=mesh, in_specs=(specs, espec),
-                         out_specs=(specs, espec), check_vma=False)(
-                             grads, errors)
+        return jax.shard_map(fn, mesh=mesh, in_specs=(specs, espec),
+                             out_specs=(specs, espec), check_vma=False)(
+                                 grads, errors)
 
     return wrapped

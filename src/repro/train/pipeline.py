@@ -20,7 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ..compat import shard_map
 
 __all__ = ["pipelined_forward"]
 
@@ -78,8 +77,8 @@ def pipelined_forward(mesh: Mesh, layer_fn, num_microbatches: int,
 
     def wrapped(stage_params, x):
         pspecs = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-        return shard_map(body, mesh=mesh,
-                         in_specs=(pspecs, P()), out_specs=P(),
-                         check_vma=False)(stage_params, x)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(pspecs, P()), out_specs=P(),
+                             check_vma=False)(stage_params, x)
 
     return wrapped

@@ -165,12 +165,12 @@ def test_vmem_rejects_block_the_old_sweep_could_pick():
     """(256, 256) at (n=512, m=8192) was selectable pre-PR6 and overflows.
 
     The old heuristic picked the largest candidate for any axis >= 256,
-    and the timed sweep would happily time it in interpret mode; the row
-    strips alone exceed the 16 MiB budget.
+    and the timed sweep would happily time it in interpret mode; the
+    double-buffered row and column strips alone exceed the 16 MiB budget.
     """
     bd = fused_vmem_breakdown(512, 8192, 256, 256)
     assert not bd.fits()
-    assert bd.u_strip + bd.mask_strip + bd.k2_strip > VMEM_BUDGET_BYTES
+    assert 2 * (bd.u_strip + bd.k2_strip) > VMEM_BUDGET_BYTES
     with pytest.raises(VmemBudgetError, match="VMEM"):
         check_fused_blocks(512, 8192, 256, 256)
 
@@ -249,6 +249,23 @@ def test_jaxpr_mll_f64_and_callback_free():
     assert audit_fit_objective() == []
 
 
+def test_jaxpr_f32_contractions_at_highest_precision():
+    from repro.analysis.jaxpr_audit import audit_matmul_precision
+
+    assert audit_matmul_precision() == []
+
+
+def test_find_low_precision_dots_flags_default_precision():
+    import jax.numpy as jnp
+
+    from repro.analysis.jaxpr_audit import find_low_precision_dots
+
+    a = np.ones((4, 4), np.float32)
+    assert find_low_precision_dots(jax.make_jaxpr(jnp.matmul)(a, a))
+    assert not find_low_precision_dots(jax.make_jaxpr(
+        lambda x, y: jnp.matmul(x, y, precision="highest"))(a, a))
+
+
 def test_jaxpr_fused_mvm_clean():
     from repro.analysis.jaxpr_audit import audit_fused_mvm
 
@@ -281,6 +298,34 @@ def test_find_host_callbacks_detects_callback():
 
     jaxpr = jax.make_jaxpr(f)(np.zeros(3, np.float32))
     assert find_host_callbacks(jaxpr)
+
+
+@pytest.mark.parametrize("wrap", ["jit", "scan", "shard_map"])
+def test_jaxpr_walker_descends_into_sub_jaxprs(wrap):
+    """A promotion nested in a jit / scan / shard_map body is found: the
+    walker recognises ``jax.extend.core`` jaxprs, not just the top level."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from repro.analysis.jaxpr_audit import find_f64, iter_eqns
+
+    def inner(x):
+        return jnp.sin(x.astype(jnp.float64)).astype(jnp.float32)
+
+    if wrap == "jit":
+        f = jax.jit(inner)
+    elif wrap == "scan":
+        def f(x):
+            return jax.lax.scan(lambda c, _: (inner(c), None), x, None,
+                                length=2)[0]
+    else:
+        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+        f = jax.shard_map(inner, mesh=mesh, in_specs=P("data"),
+                          out_specs=P("data"))
+    jaxpr = jax.make_jaxpr(f)(np.zeros(4, np.float32))
+    assert "sin" not in [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    assert "sin" in [e.primitive.name for e in iter_eqns(jaxpr)]
+    assert find_f64(jaxpr)
 
 
 # --------------------------------------------------------------------------

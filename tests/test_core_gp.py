@@ -6,11 +6,8 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # container has no hypothesis wheel; see tests/_hypcompat.py
-    from _hypcompat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (LKGPConfig, cg_solve, fit, gram_matrices,
                         init_params, joint_cov_packed, joint_grams,
@@ -173,6 +170,23 @@ def test_matheron_posterior_matches_exact_gp():
     var_ref = np.diag(cov_ref).reshape(n + 3, m) * float(state.y_tf.scale) ** 2
     emp_var = np.asarray(jnp.var(samples, 0))
     np.testing.assert_allclose(emp_var, var_ref, rtol=0.25, atol=0.05)
+
+
+def test_matheron_prior_draws_finite_for_smooth_f32_gram():
+    """An f32 RBF Gram over 100 configs has eigenvalues below f32 rounding;
+    a Cholesky at the default 1e-6 jitter returns NaN, the prior draw must
+    not."""
+    from repro.core.matheron import prior_residual_draws
+
+    X = jnp.asarray(np.random.default_rng(0).uniform(size=(100, 2)),
+                    jnp.float32)
+    K1 = gk.rbf_ard(X, X, jnp.ones(2, jnp.float32))
+    K2 = gk.matern12(jnp.linspace(0.0, 1.0, 6, dtype=jnp.float32),
+                     jnp.linspace(0.0, 1.0, 6, dtype=jnp.float32),
+                     jnp.float32(1.0))
+    F, eps = prior_residual_draws(jax.random.PRNGKey(0), K1, K2, 80, 0.1, 3)
+    assert F.dtype == jnp.float32 and F.shape == (3, 100, 6)
+    assert bool(jnp.all(jnp.isfinite(F))) and bool(jnp.all(jnp.isfinite(eps)))
 
 
 def test_fit_recovers_signal_and_improves_mll():

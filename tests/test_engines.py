@@ -189,6 +189,41 @@ def test_use_pallas_flag_changes_executed_path(monkeypatch):
     assert calls["n"] > 0, "use_pallas=True must route MVMs through kernels.ops"
 
 
+def test_pallas_engine_casts_f64_at_the_kernel_boundary():
+    """No 64-bit array reaches a pallas_call (Mosaic refuses them): f64
+    operands are cast to f32 for the kernel and the result cast back, so
+    the operator keeps the caller's dtype at f32 accuracy."""
+    task = _small_task(n=12, m=10)
+    data = GPData(jnp.asarray(task.X), jnp.asarray(task.t, jnp.float64),
+                  None, jnp.asarray(task.mask))
+    params = init_params(data.X.shape[1], jnp.float64)
+    K1, K2 = gram_matrices(params, data.X, data.t)
+    A = get_engine("pallas").operator_from_grams(K1, K2, data.mask, 0.05)
+    u = jnp.asarray(np.random.default_rng(0).normal(size=(3, 12, 10)))
+    u = u * data.mask
+    assert u.dtype == jnp.float64
+
+    out = A(u)
+    assert out.dtype == jnp.float64
+    ref = lk_operator(K1, K2, data.mask, 0.05)(u)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
+                               atol=1e-5)
+
+    def kernel_operand_dtypes(jaxpr):
+        from jax.extend.core import ClosedJaxpr, Jaxpr
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield from (v.aval.dtype for v in eqn.invars)
+            for p in eqn.params.values():
+                if isinstance(p, ClosedJaxpr):
+                    yield from kernel_operand_dtypes(p.jaxpr)
+                elif isinstance(p, Jaxpr):
+                    yield from kernel_operand_dtypes(p)
+
+    dtypes = list(kernel_operand_dtypes(jax.make_jaxpr(A)(u).jaxpr))
+    assert dtypes and all(dt == jnp.float32 for dt in dtypes), dtypes
+
+
 def test_exact_engine_methods_are_honoured_by_make_mll():
     """make_mll must route exact engines through their own solve/logdet."""
     from repro.core import DenseEngine

@@ -3,11 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # container has no hypothesis wheel; see tests/_hypcompat.py
-    from _hypcompat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import (CANDIDATE_BLOCKS, autotune_blocks, lk_mvm_fused,
                            lk_mvm_pallas, lk_mvm_ref, lk_mvm_two_stage,

@@ -19,7 +19,8 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-from _hypcompat import given, settings, st  # noqa: E402
+from hypothesis import given, settings  # noqa: E402
+from hypothesis import strategies as st  # noqa: E402
 from repro.core import (GuardedSolveError, LKGPConfig,  # noqa: E402
                         ObservationError, extend, fit, get_engine,
                         gram_matrices, guarded_solve, guarded_solve_stacked,
@@ -201,7 +202,7 @@ def test_stacked_solve_healthy_keeps_logdet_and_diagnostics():
 # --------------------------------------------------------------------------
 # property: the escalation ladder is deterministic
 # --------------------------------------------------------------------------
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(policy=st.sampled_from(["escalate", "best_effort"]),
        retries=st.integers(0, 3), seed=st.integers(0, 4))
 def test_escalation_is_deterministic(policy, retries, seed):

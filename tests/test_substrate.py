@@ -7,11 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # container has no hypothesis wheel; see tests/_hypcompat.py
-    from _hypcompat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.models.layers import (_chunked_attention, _plain_attention,
                                  chunked_ce_loss)
