@@ -280,15 +280,17 @@ class TracedBranchRule(Rule):
 # RA103: host syncs inside Python loops
 # --------------------------------------------------------------------------
 _SYNC_BUILTINS = {"float", "int", "bool"}
-_SYNC_ATTRS = {"item", "block_until_ready", "device_get", "asarray", "array"}
+# host_read: repro.core.telemetry's counted np.asarray, a sync all the same
+_SYNC_ATTRS = {"item", "block_until_ready", "device_get", "asarray", "array",
+               "host_read"}
 
 
 class HostSyncInLoopRule(Rule):
     id = "RA103"
     name = "host-sync-in-loop"
     severity = "warning"
-    rationale = ("float()/.item()/np.asarray/jax.device_get on a device "
-                 "value blocks on the accelerator; inside a Python loop "
+    rationale = ("float()/.item()/np.asarray/jax.device_get/host_read on a "
+                 "device value blocks on the accelerator; inside a Python loop "
                  "(e.g. a solver driver) that is one sync per iteration and "
                  "the async dispatch pipeline is dead.")
 
@@ -297,6 +299,8 @@ class HostSyncInLoopRule(Rule):
             if (node.func.id in _SYNC_BUILTINS and node.args
                     and not isinstance(node.args[0], ast.Constant)):
                 return f"{node.func.id}()"
+            if node.func.id == "host_read":
+                return "host_read()"
             return None
         if isinstance(node.func, ast.Attribute):
             tail = node.func.attr
@@ -307,7 +311,7 @@ class HostSyncInLoopRule(Rule):
                 if root in ("np", "numpy"):
                     return f"{root}.{tail}()"
                 return None
-            if tail in ("block_until_ready", "device_get"):
+            if tail in _SYNC_ATTRS:
                 return f"{_dotted(node.func) or tail}()"
         return None
 

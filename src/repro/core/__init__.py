@@ -9,7 +9,9 @@ Layered as state -> engines -> posterior:
   ``distributed``) selected by ``LKGPConfig.backend``;
 * :mod:`~repro.core.posterior` — lazy :class:`Posterior` with a cached
   ``K^{-1} y`` shared between the exact mean and Matheron samples;
-* :mod:`~repro.core.lkgp` — the legacy :class:`LKGP` facade.
+* :mod:`~repro.core.lkgp` — the legacy :class:`LKGP` facade;
+* :mod:`~repro.core.telemetry` — the program's spans and counters (host
+  steps, host reads, solves, compiles), on the profiler trace's clock.
 
 Supporting numerics: the pluggable solver stack — grid-form CG/PCG/SGD
 (:mod:`~repro.core.solvers`, with ``LKGPConfig.solver`` selecting the

@@ -28,12 +28,12 @@ from __future__ import annotations
 
 import functools
 import math
-import threading
 from typing import Callable, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
 
+from . import telemetry
 from .caching import LRUCache
 from .gp_kernels import HIGHEST
 from .mvm import kron_dense, lk_mvm
@@ -53,39 +53,23 @@ __all__ = [
 
 _LOG_2PI = math.log(2.0 * math.pi)
 
-# Process-wide count of engine solve entries. Eager solves (the posterior
-# hot path) bump it once per call; solves inside a jitted objective bump it
-# once per TRACE, not per execution — so this is a cache-verification aid
-# ("did that posterior() call re-solve?"), not a performance counter. The
-# serving benchmark asserts it stays flat across a warm posterior() re-read.
-# Engines are shared singletons and PredictionService solves from multiple
-# tenant threads, so the read-modify-write must be lock-guarded — an
-# unguarded `+= 1` can drop counts across an interpreter switch.
-_solve_tally = 0
-_TALLY_LOCK = threading.Lock()
-
-
 def solve_tally() -> int:
-    """Monotonic count of engine solve entries in this process."""
-    return _solve_tally
+    """Engine solve entries in this process (the counter ``solver.entries``
+    of :mod:`repro.core.telemetry`): one per eager solve, plus one per extra
+    escalation-ladder attempt. Solves inside a jit trace are not counted.
+    A cache-verification aid ("did that posterior() call re-solve?"): the
+    serving benchmark asserts it stays flat across a warm posterior()
+    re-read."""
+    return int(telemetry.total("solver.entries"))
 
 
-def _bump_tally(n: int = 1) -> None:
-    global _solve_tally
-    with _TALLY_LOCK:
-        _solve_tally += n
-
-
-def _bump_escalations(res) -> None:
-    """Count extra escalation-ladder attempts as solve entries.
-
-    Guarded eager solves attach their escalation trace (one entry per
-    attempt, including the base one); each attempt beyond the first was a
-    full extra solve against the operator, so the tally reflects it.
-    """
+def _count_entries(res) -> None:
+    """Count an eager solve, and each escalation attempt beyond its first
+    (guarded solves attach one trace entry per attempt)."""
+    if isinstance(res.x, jax.core.Tracer):
+        return
     trace = getattr(res, "trace", None)
-    if trace and len(trace) > 1:
-        _bump_tally(len(trace) - 1)
+    telemetry.count("solver.entries", len(trace) if trace else 1)
 
 
 @runtime_checkable
@@ -212,20 +196,22 @@ class DenseEngine:
 
     def solve(self, A, b, config, x0=None):
         # x0 is accepted for interface uniformity; the exact solve ignores it.
-        _bump_tally()
         if not isinstance(A, _DenseOperator):
             # Non-dense operator handed to the dense engine: route through
             # the guarded iterative solve and keep the diagnostics (this
             # path used to drop them entirely).
             res = guarded_solve(A, b, config, x0=x0)
-            _bump_escalations(res)
+            _count_entries(res)
             _stash_diagnostics(A, res)
             return res.x
         L = A.chol()
         N = A.mask.size
         bb = (b * A.mask).reshape(-1, N)          # (batch, N)
         x = jax.scipy.linalg.cho_solve((L, True), bb.T).T
-        return (x * A.mask.reshape(-1)).reshape(b.shape)
+        x = (x * A.mask.reshape(-1)).reshape(b.shape)
+        if not isinstance(x, jax.core.Tracer):
+            telemetry.count("solver.entries")
+        return x
 
     def logdet(self, A, data, config, probes=None):
         L = A.chol()
@@ -307,9 +293,8 @@ class IterativeEngine:
         (:mod:`repro.core.solvers.guarded`); traced solves pass through
         unguarded.
         """
-        _bump_tally()
         res = guarded_solve(A, b, config, x0=x0)
-        _bump_escalations(res)
+        _count_entries(res)
         _stash_diagnostics(A, res)
         return res
 
@@ -328,10 +313,9 @@ class IterativeEngine:
         ``config.solve_policy`` escalation guard; traced solves pass
         through unguarded.
         """
-        _bump_tally()
         st = guarded_solve_stacked(A, rhs, config, probe_cols=probe_cols,
                                    subspace_dim=subspace_dim, x0=x0)
-        _bump_escalations(st.result)
+        _count_entries(st.result)
         _stash_diagnostics(A, st.result)
         return st
 

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import gp_kernels as gk
+from . import telemetry
 from .engines import get_engine
 from .matheron import kronecker_correction, prior_residual_draws
 from .mvm import kron_dense
@@ -122,8 +123,10 @@ class Posterior:
             # config and observation count.
             engine = getattr(state, "engine", None)
         if engine is None:
-            n_obs = int(np.sum(np.asarray(state.mask)))
-            engine = get_engine(resolve_backend(state.config, n_obs))
+            with telemetry.span("posterior.engine"):
+                mask = telemetry.host_read(state.mask, "posterior.n_obs")
+                engine = get_engine(resolve_backend(state.config,
+                                                    int(np.sum(mask))))
         self._engine = engine
         self._alpha: jnp.ndarray | None = None   # cached K^{-1}(Y*mask)
         self._solve_info: Any = None  # CGResult of most recent engine solve
@@ -132,7 +135,8 @@ class Posterior:
     # -- cached pieces -----------------------------------------------------
     @cached_property
     def _grams(self):
-        return joint_grams(self._state, self._Xs)
+        with telemetry.span("posterior.grams"):
+            return joint_grams(self._state, self._Xs)
 
     @cached_property
     def _operator(self):
@@ -145,7 +149,8 @@ class Posterior:
 
     def _solve(self, rhs):
         """Engine solve capturing the block solver's diagnostics."""
-        x = self._engine.solve(self._operator, rhs, self._state.config)
+        with telemetry.span("posterior.solve"):
+            x = self._engine.solve(self._operator, rhs, self._state.config)
         self._solve_info = getattr(self._operator, "last_result", None)
         self._n_solves += 1
         return x
@@ -198,10 +203,11 @@ class Posterior:
         n_samples = n_samples or cfg.posterior_samples
         K1a, K2 = self._grams
         n = st.n
-        noise = jnp.exp(st.params.raw_noise)
-        F, eps = prior_residual_draws(key, K1a, K2, n, noise, n_samples,
-                                      jitter=cfg.jitter)
-        resid = st.mask * (F[:, :n, :] + eps)
+        with telemetry.span("posterior.draws"):
+            noise = jnp.exp(st.params.raw_noise)
+            F, eps = prior_residual_draws(key, K1a, K2, n, noise, n_samples,
+                                          jitter=cfg.jitter)
+            resid = st.mask * (F[:, :n, :] + eps)
         if self._alpha is None:
             Ym = st.y_tf(st.Y) * st.mask
             sol = self._solve(jnp.concatenate([Ym[None], resid], axis=0))
@@ -210,8 +216,9 @@ class Posterior:
         else:
             # Linearity: K^{-1}(Y - F - eps) = alpha - K^{-1}(F + eps).
             u = self._alpha[None] - self._solve(resid)
-        raw = F + kronecker_correction(K1a, u, K2, n)
-        return st.y_tf.inverse(raw)
+        with telemetry.span("posterior.products"):
+            raw = F + kronecker_correction(K1a, u, K2, n)
+            return st.y_tf.inverse(raw)
 
     @cached_property
     def _default_samples(self):
@@ -247,9 +254,10 @@ class Posterior:
                 key = jax.random.fold_in(
                     jax.random.PRNGKey(st.config.seed), 2)
             s = self.samples(key, n_samples)[:, :, -1]
-        mean = self.mean[:, -1]
-        var_f = jnp.var(s, axis=0)
-        var_y = var_f + st.y_tf.inverse_var(jnp.exp(st.params.raw_noise))
+        with telemetry.span("posterior.products"):
+            mean = self.mean[:, -1]
+            var_f = jnp.var(s, axis=0)
+            var_y = var_f + st.y_tf.inverse_var(jnp.exp(st.params.raw_noise))
         return mean, var_y
 
 

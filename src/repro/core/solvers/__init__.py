@@ -16,7 +16,7 @@ from .base import (CGSolver, PCGSolver, SGDSolver, Solver, SOLVERS,
 from .cg import CGResult, CGTridiag, cg_solve, cg_solve_tridiag
 from .guarded import (SOLVE_POLICIES, EscalationStep, GuardedSolveError,
                       GuardedSolver, escalation_tally, guarded_solve,
-                      guarded_solve_stacked, reset_escalation_tally)
+                      guarded_solve_stacked)
 from .pcg import pcg_solve
 from .sgd import estimate_lmax, sgd_solve
 
@@ -28,5 +28,4 @@ __all__ = [
     "CGSolver", "PCGSolver", "SGDSolver",
     "GuardedSolver", "GuardedSolveError", "EscalationStep", "SOLVE_POLICIES",
     "guarded_solve", "guarded_solve_stacked", "escalation_tally",
-    "reset_escalation_tally",
 ]

@@ -36,8 +36,13 @@ already tolerate.
 Every guarded result carries its escalation ``trace`` (a tuple of
 :class:`EscalationStep`) on ``CGResult.trace``, which flows through
 ``_stash_diagnostics`` into ``Posterior.solve_info``. Ladder activity is
-counted per stage (:func:`escalation_tally`); the engines additionally
-bump :func:`repro.core.engines.solve_tally` once per extra attempt.
+counted per stage (:func:`escalation_tally`, the ``solver.<stage>``
+counters of :mod:`repro.core.telemetry`); the engines additionally count
+each extra attempt in :func:`repro.core.engines.solve_tally`. Eager solves
+are spanned: ``solver.dispatch`` (the base solver's call, up to its
+unfinished loop: the host's trace, lowering, cache lookup and launch),
+``solver.guard`` (the health verdict, whose host reads wait for the loop)
+and ``solver.escalate`` (each rung after the first).
 
 **Tracing:** the guards are host-side control flow. Inside a traced
 program (jit/vmap — e.g. the fit objective) the diagnostics are tracers,
@@ -49,20 +54,20 @@ and any direct ``engine.solve*`` call.
 """
 from __future__ import annotations
 
-import threading
+from contextlib import nullcontext
 from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from .base import StackedSolveResult, Solver, get_solver, resolve_solver
 from .cg import CGResult
 
 __all__ = [
     "EscalationStep", "GuardedSolveError", "GuardedSolver", "SOLVE_POLICIES",
     "guarded_solve", "guarded_solve_stacked", "escalation_tally",
-    "reset_escalation_tally",
 ]
 
 SOLVE_POLICIES = ("strict", "escalate", "best_effort")
@@ -94,29 +99,18 @@ class GuardedSolveError(RuntimeError):
         self.trace = trace
 
 
-# -- ladder activity counters (process-wide, mirrors engines.solve_tally) --
-_TALLY_LOCK = threading.Lock()
-_TALLY: dict[str, int] = {
-    "retry_jitter": 0, "switch_solver": 0, "dense_fallback": 0,
-    "degraded_returns": 0, "strict_failures": 0,
-}
+# -- ladder activity counters --------------------------------------------
+_STAGES = ("retry_jitter", "switch_solver", "dense_fallback",
+           "degraded_returns", "strict_failures")
 
 
 def escalation_tally() -> dict[str, int]:
     """Counts of escalation-ladder activity in this process, by stage."""
-    with _TALLY_LOCK:
-        return dict(_TALLY)
-
-
-def reset_escalation_tally() -> None:
-    with _TALLY_LOCK:
-        for k in _TALLY:
-            _TALLY[k] = 0
+    return {k: int(telemetry.total(f"solver.{k}")) for k in _STAGES}
 
 
 def _bump(stage: str) -> None:
-    with _TALLY_LOCK:
-        _TALLY[stage] = _TALLY.get(stage, 0) + 1
+    telemetry.count(f"solver.{stage}")
 
 
 # -- health ---------------------------------------------------------------
@@ -124,8 +118,19 @@ def _is_traced(value: Any) -> bool:
     return isinstance(value, jax.core.Tracer)
 
 
+def _eager(A: Any, b: Any, x0: Any) -> bool:
+    """No tracer among the right-hand side, the warm start and the
+    operator's factors: the solve runs eagerly, outside any jit trace."""
+    return not any(_is_traced(v) for v in (
+        b, x0, *(getattr(A, a, None) for a in _FACTOR_ATTRS)))
+
+
+def _spanned(name: str, eager: bool):
+    return telemetry.span(name) if eager else nullcontext()
+
+
 def _worst_residual(res: CGResult) -> float:
-    rel = np.asarray(res.rel_residual)
+    rel = telemetry.host_read(res.rel_residual, "solver.worst_residual")
     if rel.size == 0:
         return 0.0
     return float(np.max(np.nan_to_num(rel, nan=np.inf, posinf=np.inf,
@@ -140,9 +145,11 @@ def _degraded(res: CGResult) -> bool:
     to sync ``x`` separately. Residuals above tolerance do NOT count:
     hitting ``max_iters`` on a hard system is expected behaviour.
     """
-    if res.breakdown is not None and bool(np.any(np.asarray(res.breakdown))):
+    if res.breakdown is not None and bool(np.any(
+            telemetry.host_read(res.breakdown, "solver.breakdown"))):
         return True
-    return not bool(np.all(np.isfinite(np.asarray(res.rel_residual))))
+    return not bool(np.all(np.isfinite(
+        telemetry.host_read(res.rel_residual, "solver.residual"))))
 
 
 class _JitteredOperator:
@@ -203,7 +210,7 @@ def _dense_solve(A: Any, b: jnp.ndarray, config: Any) -> CGResult:
     K = kron_dense(A.K1, A.K2) * (mv[:, None] * mv[None, :])
     K = K + jnp.diag(A.noise * mv + (1.0 - mv))
     L = jnp.linalg.cholesky(K)
-    if not bool(np.all(np.isfinite(np.asarray(L)))):
+    if not bool(np.all(np.isfinite(telemetry.host_read(L, "solver.dense_chol")))):
         cap = float(getattr(config, "guard_jitter_max", 1e-2))
         L = jnp.linalg.cholesky(K + cap * jnp.eye(K.shape[0], dtype=K.dtype))
     N = mv.shape[0]
@@ -258,9 +265,11 @@ def _run_ladder(attempt: Callable[[Solver, Callable], CGResult],
     def run(stage: str, solver: Solver, op: Callable, eps: float,
             pre: CGResult | None = None) -> tuple[CGResult, bool]:
         nonlocal best, best_score
-        res = pre if pre is not None else attempt(solver, op)
-        ok = not _degraded(res)
-        score = _worst_residual(res)
+        with telemetry.span("solver.guard" if pre is not None
+                            else "solver.escalate"):
+            res = pre if pre is not None else attempt(solver, op)
+            ok = not _degraded(res)
+            score = _worst_residual(res)
         trace.append(EscalationStep(stage=stage, solver=solver.name,
                                     jitter=eps, ok=ok, worst_residual=score))
         if best is None or score < best_score:
@@ -289,9 +298,10 @@ def _run_ladder(attempt: Callable[[Solver, Callable], CGResult],
             return res, tuple(trace)
     if dense_attempt is not None and _dense_eligible(A, config):
         _bump("dense_fallback")
-        res = dense_attempt()
-        ok = not _degraded(res)
-        score = _worst_residual(res)
+        with telemetry.span("solver.escalate"):
+            res = dense_attempt()
+            ok = not _degraded(res)
+            score = _worst_residual(res)
         trace.append(EscalationStep(stage="dense_fallback", solver="dense",
                                     jitter=0.0, ok=ok, worst_residual=score))
         if ok:
@@ -321,13 +331,15 @@ def guarded_solve(A: Callable, b: jnp.ndarray, config: Any,
     unchanged (``trace=None``).
     """
     base = solver if solver is not None else resolve_solver(config, A)
-    res = base.solve(A, b, config, x0=x0)
+    with _spanned("solver.dispatch", _eager(A, b, x0)):
+        res = base.solve(A, b, config, x0=x0)
     if _is_traced(res.rel_residual):
         return res
-    if _policy(config) != "strict" and not _degraded(res):
-        # Fast path: healthy first attempt, record a one-step trace.
-        return res._replace(trace=(EscalationStep(
-            "attempt", base.name, 0.0, True, _worst_residual(res)),))
+    with telemetry.span("solver.guard"):
+        if _policy(config) != "strict" and not _degraded(res):
+            # Fast path: healthy first attempt, record a one-step trace.
+            return res._replace(trace=(EscalationStep(
+                "attempt", base.name, 0.0, True, _worst_residual(res)),))
 
     def attempt(slv: Solver, op: Callable) -> CGResult:
         return slv.solve(op, b, config, x0=x0)
@@ -352,14 +364,16 @@ def guarded_solve_stacked(A: Callable, rhs: jnp.ndarray, config: Any, *,
     probe estimate it replaces.
     """
     base = solver if solver is not None else resolve_solver(config, A)
-    st = base.solve_stacked(A, rhs, config, probe_cols=probe_cols,
-                            subspace_dim=subspace_dim, x0=x0)
+    with _spanned("solver.dispatch", _eager(A, rhs, x0)):
+        st = base.solve_stacked(A, rhs, config, probe_cols=probe_cols,
+                                subspace_dim=subspace_dim, x0=x0)
     if _is_traced(st.result.rel_residual):
         return st
-    if _policy(config) != "strict" and not _degraded(st.result):
-        res = st.result._replace(trace=(EscalationStep(
-            "attempt", base.name, 0.0, True, _worst_residual(st.result)),))
-        return st._replace(result=res)
+    with telemetry.span("solver.guard"):
+        if _policy(config) != "strict" and not _degraded(st.result):
+            res = st.result._replace(trace=(EscalationStep(
+                "attempt", base.name, 0.0, True, _worst_residual(st.result)),))
+            return st._replace(result=res)
 
     results: dict[int, StackedSolveResult] = {id(st.result): st}
 

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import gp_kernels as gk
+from . import telemetry
 from .caching import LRUCache
 from .errors import ObservationError, check_grid_columns, check_observed_finite
 from .lbfgs import lbfgs_minimize
@@ -452,6 +453,7 @@ def _polish_fit(cfg: LKGPConfig, engine, d: int, dtype, budget: int,
     return params, res
 
 
+@telemetry.span("state.fit")
 def fit(X, t, Y, mask, config: LKGPConfig | None = None,
         params0: LKGPParams | None = None, engine=None, *,
         init=None, polish_steps: int | None = None,
@@ -496,7 +498,7 @@ def fit(X, t, Y, mask, config: LKGPConfig | None = None,
     Xn, tn, Yn = x_tf(X), t_tf(t), y_tf(Y)
 
     d = X.shape[1]
-    n_obs = int(np.sum(np.asarray(mask)))
+    n_obs = int(np.sum(telemetry.host_read(mask, "fit.n_obs")))
     explicit_engine = engine is not None
     backend = engine.name if explicit_engine else resolve_backend(cfg, n_obs)
     if engine is None:
@@ -705,6 +707,7 @@ def stack_states(states: list[LKGPState]) -> LKGPState:
     return jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves), *states)
 
 
+@telemetry.span("state.extend")
 def extend(state: LKGPState, new_Y, new_mask, new_X=None) -> LKGPState:
     """Incremental conditioning: fold new observations into the state.
 
@@ -740,7 +743,8 @@ def extend(state: LKGPState, new_Y, new_mask, new_X=None) -> LKGPState:
         if new_Y.shape != state.Y.shape:
             raise ValueError(f"full-grid update expects shape {state.Y.shape}, "
                              f"got {new_Y.shape}")
-        old_m, upd_m = np.asarray(state.mask), np.asarray(new_mask)
+        old_m = telemetry.host_read(state.mask, "extend.mask")
+        upd_m = telemetry.host_read(new_mask, "extend.mask")
         if np.any(upd_m < old_m):
             raise ValueError("new_mask must be a superset of the current mask")
         X, Y, mask = state.X, new_Y, new_mask
@@ -768,6 +772,7 @@ def extend(state: LKGPState, new_Y, new_mask, new_X=None) -> LKGPState:
     return out
 
 
+@telemetry.span("state.refit")
 def refit(state: LKGPState, config: LKGPConfig | None = None,
           lbfgs_iters: int | None = None, engine=None, *,
           init=None, polish_steps: int | None = None,

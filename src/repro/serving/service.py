@@ -40,6 +40,7 @@ from typing import Sequence
 
 import numpy as np
 
+from ..core import telemetry
 from ..core.errors import ObservationError
 from ..core.posterior import posterior_batch
 from ..core.solvers.guarded import GuardedSolveError
@@ -115,6 +116,7 @@ class PredictionService:
         }
 
     # -- observation path --------------------------------------------------
+    @telemetry.span("service.observe")
     def observe(self, tenant: str, task: str, Y, mask,
                 X=None, t=None) -> dict:
         """Stream observations into a session; creates it on first call.
@@ -330,6 +332,7 @@ class PredictionService:
         return [self._finalize(s, mean_np[i], var_np[i], len(group))
                 for i, s in enumerate(group)]
 
+    @telemetry.span("service.predict")
     def predict(self, tenant: str, task: str) -> Prediction:
         """Final-value prediction for one session (batch of one)."""
         start = time.perf_counter()
@@ -339,6 +342,7 @@ class PredictionService:
         self.predict_latency.record(time.perf_counter() - start)
         return result
 
+    @telemetry.span("service.predict")
     def predict_many(self, keys: Sequence[tuple[str, str]]) -> list[Prediction]:
         """Coalesced predictions: stackable sessions share one vmapped call.
 
@@ -382,4 +386,8 @@ class PredictionService:
             # evictions; evictions here mean recompiles in the latency path.
             "compiled_caches": {**compiled_cache_stats(),
                                 "engines": engine_cache_stats()},
+            # the program's own counters (core/telemetry.py): compiles and
+            # re-traces (jax.*), host reads (sync.*), solves and escalations
+            # (solver.*), process-wide
+            "telemetry": telemetry.snapshot(),
         }

@@ -277,11 +277,11 @@ def test_batcher_isolates_group_failures():
 
 
 def test_metrics_shape():
-    svc, _ = make_service(["t0", "t1"])
+    svc, tasks = make_service(["t0", "t1"])
     svc.predict("t0", "run")
     m = svc.metrics()
     assert set(m) == {"store", "predict_latency", "observe_latency",
-                      "counters", "events", "compiled_caches"}
+                      "counters", "events", "compiled_caches", "telemetry"}
     assert m["counters"]["predicts"] == 1
     assert m["counters"]["observes"] == 2
     assert m["predict_latency"]["count"] == 1
@@ -291,16 +291,25 @@ def test_metrics_shape():
         stats = m["compiled_caches"][cache]
         assert {"size", "maxsize", "hits", "misses",
                 "evictions"} <= set(stats)
+    # the program's own counters: compiles and re-traces, host reads
+    tel = m["telemetry"]
+    assert {"jax.traces", "jax.lowerings", "jax.executables",
+            "jax.cache_hits", "jax.compile_s"} <= set(tel)
+    svc.observe("t0", "run", tasks["t0"].Y, grow_mask(tasks["t0"].mask))
+    after = svc.metrics()["telemetry"]
+    assert after["sync.extend.mask"] == tel.get("sync.extend.mask", 0) + 2
+    assert after["jax.traces"] >= tel["jax.traces"]
 
 
 def test_solve_tally_is_thread_safe():
-    """The engine solve tally is bumped from every tenant thread of a
-    PredictionService; an unguarded read-modify-write drops counts across
-    interpreter switches. Hammer _bump_tally from many threads with an
-    aggressive switch interval and require an EXACT count."""
+    """The engine solve tally (the telemetry counter ``solver.entries``) is
+    bumped from every tenant thread of a PredictionService; an unguarded
+    read-modify-write drops counts across interpreter switches. Hammer it
+    from many threads with an aggressive switch interval and require an
+    EXACT count."""
     import sys
 
-    from repro.core import engines
+    from repro.core import engines, telemetry
 
     n_threads, n_bumps = 8, 2000
     before = engines.solve_tally()
@@ -309,7 +318,7 @@ def test_solve_tally_is_thread_safe():
     try:
         def hammer():
             for _ in range(n_bumps):
-                engines._bump_tally()
+                telemetry.count("solver.entries")
 
         threads = [threading.Thread(target=hammer)
                    for _ in range(n_threads)]

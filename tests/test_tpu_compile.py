@@ -103,3 +103,28 @@ def test_pallas_engine_mll_value_and_grad_compiles(one_chip, monkeypatch):
                    _spec(one_chip, M), _spec(one_chip, N, M),
                    _spec(one_chip, N, M),
                    _spec(one_chip, cfg.slq_probes, N, M))
+
+
+def test_fused_kernel_keeps_its_hlo_name_under_any_jit(one_chip):
+    """The fused call is named ``lk_mvm_fused`` in the compiled program
+    even when a jit of another name (a whole solve loop, say) wraps it
+    directly: the benchmark's roofline reader matches that name."""
+    import re
+
+    def cg_like_loop(K1, K2, mask, u):
+        def body(c):
+            i, x = c
+            y = lk_mvm._fused_call(K1, K2, mask, x, mask * x, 0.1,
+                                   block_n=128, block_m=128,
+                                   precision="f32", interpret=False)
+            return i + 1, x + 1e-3 * y[:, :N, :M]
+        return jax.lax.while_loop(lambda c: c[0] < 3, body, (0, u))[1]
+
+    text = jax.jit(cg_like_loop).lower(
+        _spec(one_chip, N, N), _spec(one_chip, M, M), _spec(one_chip, N, M),
+        _spec(one_chip, 65, N, M)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and " = " in ln]
+    assert calls and all(
+        re.search(r"lk_mvm_fused\S* = .*tpu_custom_call", ln) for ln in calls)
+    assert re.search(r" = f32\[65,\d+,52\]", calls[0])  # B read from it
