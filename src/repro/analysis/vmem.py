@@ -1,10 +1,13 @@
-"""Static VMEM budget checker for the fused latent-Kronecker MVM kernel.
+"""Static VMEM budget checker for the fused latent-Kronecker MVM kernels.
 
-The fused kernel (:mod:`repro.kernels.lk_mvm`, shared by ``lk_mvm_fused``
-and ``lk_mvm_fused_rows``) keeps, per grid step, a K1 block, a full row
+The row-strip fused kernel (:mod:`repro.kernels.lk_mvm` ``_fused_call``,
+behind ``lk_mvm_fused_rows`` and, where m is too wide for the square
+layout, ``lk_mvm_fused``) keeps, per grid step, a K1 block, a full row
 strip of the masked input ``mask * U``, a full K2 column strip, the
 (i, j) mask and U tiles of the epilogue, the output block, and one f32
-accumulator resident in VMEM.
+accumulator resident in VMEM. The square kernel (``_square_call``, behind
+``lk_mvm_fused``) works in the transposed, lane-dense layout and keeps a
+chunk of right-hand sides per step (:func:`square_plan`).
 TPU VMEM is ~16 MiB per core; a (block_n, block_m) choice whose resident
 set exceeds it fails at ``pallas_call`` compile time on hardware — long
 after the autotuner committed to it, and invisibly on CPU where the
@@ -22,6 +25,8 @@ the pipeline's double buffering) so oversized configurations are rejected
   choice exceeds the budget (called by the fused kernels themselves);
 * :func:`best_fitting_blocks` — the largest-throughput candidate pair
   that fits (used by the autotuner to filter its sweep);
+* :func:`square_plan` / :func:`square_vmem_breakdown` — the square
+  kernel's tiles and batch chunk, and their byte model;
 * :func:`audit_candidate_space` — sweep representative shape buckets and
   report every (shape, candidate) combination the autotuner could emit
   that does not fit; the *filtered* sweep is provably clean while the raw
@@ -36,7 +41,8 @@ from dataclasses import dataclass
 __all__ = ["VMEM_BUDGET_BYTES", "VmemBudgetError", "VmemBreakdown",
            "block_edge", "effective_blocks", "fused_vmem_breakdown",
            "fused_vmem_bytes", "check_fused_blocks", "best_fitting_blocks",
-           "audit_candidate_space"]
+           "SquarePlan", "SquareVmemBreakdown", "square_vmem_breakdown",
+           "square_plan", "audit_candidate_space"]
 
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024   # 16 MiB per TPU core
 
@@ -189,6 +195,121 @@ def best_fitting_blocks(n: int, m: int, precision: str = "f32",
                 n, m, bn, bm, precision)[:2]), -bn, -bm)
             if best_key is None or key < best_key:
                 best, best_key = (bn, bm), key
+    return best
+
+
+@dataclass(frozen=True)
+class SquarePlan:
+    """Tiles of the square fused kernel (transposed layout, see
+    ``repro.kernels.lk_mvm._square_call``). Grid (B/chunk, n_pad/tn,
+    n_pad/bk); the last axis sweeps K1."""
+    bk: int        # K1 rows per step: depth of the sweep
+    tn: int        # K1 columns per step: lanes of the output block
+    chunk: int     # right-hand sides per step, stacked on the MXU's rows
+    m8: int        # m padded to the sublane multiple
+    n_pad: int     # n padded to tn
+
+    @property
+    def ts(self) -> int:
+        """Lanes of one main product inside a step: the output block is
+        accumulated in sub-tiles of ts, which bounds the product's value."""
+        return next((t for t in (_SUBTILE, 384, 256) if self.tn % t == 0),
+                    self.tn)
+
+    def grid(self, B: int) -> tuple[int, int, int]:
+        return (-(-B // self.chunk), self.n_pad // self.tn,
+                self.n_pad // self.bk)
+
+    def steps(self, B: int) -> int:
+        gc, gj, gk = self.grid(B)
+        return gc * gj * gk
+
+
+@dataclass(frozen=True)
+class SquareVmemBreakdown:
+    """Per-grid-step VMEM bytes of the square fused kernel: its
+    BlockSpecs and scratch exactly, Mosaic's temporaries as a bound."""
+    um_block: int        # (chunk, m8, bk) (mask * U)^T strip
+    k2t: int             # (m8, m8) K2^T, whole
+    k1_block: int        # (bk, tn) K1^T block
+    mask_block: int      # (m8, tn) mask^T block
+    out_block: int       # (chunk, m8, tn) f32 output, the accumulator
+    t_scratch: int       # (chunk * m8, bk) stage-R tile
+    product: int         # (chunk * m8, ts) f32 value of one main product
+    temporaries: int     # Mosaic's values around the product (bound)
+    total: int
+
+    def fits(self, budget: int = VMEM_BUDGET_BYTES) -> bool:
+        return self.total <= budget
+
+
+def square_vmem_breakdown(plan: SquarePlan,
+                          precision: str = "f32") -> SquareVmemBreakdown:
+    """VMEM model of one square-kernel grid step.
+
+    Mirrors its BlockSpecs byte for byte: inputs and the output are double
+    buffered by the Pallas pipeline; the stage-R scratch is one buffer,
+    and one lane sub-tile of the main product's f32 value is held before
+    it is added to the output. Mosaic's own values around that product
+    (the operands split into bf16 parts at HIGHEST, the masked product)
+    are bounded by twice the stage-R tile and K1 sub-tile plus one more
+    product: compiled for a v5e, the kernel needed less than this bound
+    at every plan tried, and up to 3.3 MB more than the BlockSpecs alone.
+    """
+    ib = _ITEMSIZE[precision]
+    c, m8, bk, tn = plan.chunk, plan.m8, plan.bk, plan.tn
+    um = c * _tile_bytes(m8, bk, ib)
+    k2t = _tile_bytes(m8, m8, ib)
+    k1 = _tile_bytes(bk, tn, ib)
+    mask = _tile_bytes(m8, tn, ib)
+    out = c * _tile_bytes(m8, tn, 4)
+    t = _tile_bytes(c * m8, bk, ib)
+    prod = _tile_bytes(c * m8, plan.ts, 4)
+    temps = 2 * (t + _tile_bytes(bk, plan.ts, ib)) + prod
+    total = 2 * (um + k2t + k1 + mask + out) + t + prod + temps
+    return SquareVmemBreakdown(um_block=um, k2t=k2t, k1_block=k1,
+                               mask_block=mask, out_block=out, t_scratch=t,
+                               product=prod, temporaries=temps, total=total)
+
+
+_SUBTILE = 512     # lanes of one main product inside a step
+# Per-step overhead of the square kernel, in f32 multiply-adds at HIGHEST:
+# about 0.5 us a step on a v5e, where such multiply-adds run at about
+# 16e12 a second.
+_STEP_MACS = 1 << 23
+
+
+def square_plan(n: int, m: int, B: int, block_n: int,
+                precision: str = "f32",
+                budget: int = VMEM_BUDGET_BYTES) -> SquarePlan | None:
+    """The square kernel's tiles for (B, n, m), or None if none fits.
+
+    ``block_n`` sets the sweep depth bk as :func:`block_edge` does. The
+    lane tile tn (a multiple of bk; n is padded to a multiple of it) and
+    the batch chunk are chosen from the observed B: the fitting pair with
+    the least MXU work, counting the padding of n, the rows of a padded
+    last chunk and the stage-R product recomputed for each lane tile,
+    plus a fixed cost per grid step. None when K2 and one right-hand
+    side's tiles alone exceed the budget (m in the thousands); the
+    row-strip kernel takes those.
+    """
+    if precision not in _ITEMSIZE:
+        raise ValueError(f"precision must be 'f32' or 'bf16', "
+                         f"got {precision!r}")
+    m8 = _round_up(max(m, 1), _SUBLANE[_ITEMSIZE[precision]])
+    bk = block_edge(block_n, n, _MIN_EDGE[precision])
+    best, best_cost = None, None
+    for tn in range(bk, _round_up(n, bk) + 1, bk):
+        n_pad = _round_up(n, tn)
+        for chunk in range(1, max(B, 1) + 1):
+            plan = SquarePlan(bk=bk, tn=tn, chunk=chunk, m8=m8, n_pad=n_pad)
+            if not square_vmem_breakdown(plan, precision).fits(budget):
+                break            # larger chunks only grow
+            rows = plan.grid(B)[0] * chunk * m8
+            cost = (rows * n_pad * (n_pad + m8 * (n_pad // tn))
+                    + _STEP_MACS * plan.steps(B))
+            if best_cost is None or cost < best_cost:
+                best, best_cost = plan, cost
     return best
 
 

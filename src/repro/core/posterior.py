@@ -41,6 +41,7 @@ same backend — dense, iterative, pallas, or distributed — as fitting.
 """
 from __future__ import annotations
 
+import copy
 import threading
 from functools import cached_property
 from typing import Any, Protocol, runtime_checkable
@@ -273,10 +274,18 @@ _CACHE_LOCK = threading.Lock()
 
 
 def _state_cached(state, attr: str, build):
+    """``build(twin)``, cached on ``state``. ``twin`` is a shallow copy of
+    the state (same arrays and attached attributes) that holds no cached
+    posterior: a posterior holding the very state that caches it would be
+    a reference cycle, and every round's device arrays would then wait for
+    the cyclic garbage collector instead of going with their state."""
     with _CACHE_LOCK:
         post = getattr(state, attr, None)
         if post is None:
-            post = build()
+            twin = copy.copy(state)
+            for cached in (_CACHE_ATTR, _BATCH_CACHE_ATTR):
+                twin.__dict__.pop(cached, None)
+            post = build(twin)
             object.__setattr__(state, attr, post)
         return post
 
@@ -301,7 +310,7 @@ def posterior(state: LKGPState, Xs=None, engine=None,
                          "posterior: no explicit Xs or engine")
     if not cache:
         return Posterior(state, Xs=Xs, engine=engine)
-    return _state_cached(state, _CACHE_ATTR, lambda: Posterior(state))
+    return _state_cached(state, _CACHE_ATTR, Posterior)
 
 
 # -- batched exact posterior (one vmapped call over fit_batch states) ------
@@ -528,5 +537,4 @@ def posterior_batch(state: LKGPState,
         cache = state.config.posterior_cache
     if not cache:
         return BatchedPosterior(state)
-    return _state_cached(state, _BATCH_CACHE_ATTR,
-                         lambda: BatchedPosterior(state))
+    return _state_cached(state, _BATCH_CACHE_ATTR, BatchedPosterior)

@@ -1,10 +1,11 @@
 """Block-size autotuner for the fused latent-Kronecker MVM kernel.
 
-The fused kernel's best (block_n, block_m) depends on the grid shape: a
-block_n that covers n keeps the kernel in its single-K1-sweep regime (no
-stage-R recompute, every operand read once), while larger-than-needed
-blocks waste VMEM and padding FLOPs. The autotuner picks per-shape blocks
-from a small sweep over ``CANDIDATE_BLOCKS`` ({64, 128, 256}):
+``lk_mvm_fused`` runs the square kernel, where block_n is the depth of
+its K1 sweep and the lane tile and batch chunk follow from the shape
+(:func:`repro.analysis.vmem.square_plan`), or, where m is too wide for
+that layout, the row-strip kernel, where (block_n, block_m) are its
+tiles. The autotuner picks per-shape blocks from a small sweep over
+``CANDIDATE_BLOCKS`` ({64, 128, 256}):
 
 * **timed mode** (default on TPU, or ``timed=True``): each candidate is
   compiled and timed on a synthetic problem of the bucketed shape,
@@ -30,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.vmem import (best_fitting_blocks, effective_blocks,
-                             fused_vmem_breakdown)
+                             fused_vmem_breakdown, square_plan)
 
 __all__ = ["CANDIDATE_BLOCKS", "autotune_blocks", "clear_cache",
            "cache_contents"]
@@ -61,35 +62,41 @@ def _heuristic(n: int, m: int,
                precision: str = "f32") -> tuple[int, int] | None:
     """Smallest candidate covering each axis (single-sweep regime).
 
-    VMEM-guarded since PR 6: if the covering pair does not fit the 16 MiB
-    budget (``repro.analysis.vmem``), fall back to the best *fitting*
+    VMEM-guarded: the pair is kept if the kernel it selects fits the
+    16 MiB budget (``repro.analysis.vmem``): the square kernel, whose
+    smallest batch chunk fits for every B if it fits for one, or else the
+    row-strip kernel. Otherwise fall back to the best *fitting* row-strip
     candidate; None when no candidate fits at all — the fused kernel
     cannot run this shape and callers must take the two-stage path.
     """
     bn = next((c for c in CANDIDATE_BLOCKS if c >= n), CANDIDATE_BLOCKS[-1])
     bm = next((c for c in CANDIDATE_BLOCKS if c >= m), CANDIDATE_BLOCKS[-1])
-    if fused_vmem_breakdown(n, m, bn, bm, precision).fits():
+    if (square_plan(n, m, 1, bn, precision) is not None
+            or fused_vmem_breakdown(n, m, bn, bm, precision).fits()):
         return bn, bm
     return best_fitting_blocks(n, m, precision,
                                candidates=CANDIDATE_BLOCKS)
 
 
-def _candidate_pairs(n: int, m: int, precision: str = "f32"):
+def _candidate_pairs(n: int, m: int, B: int = 1, precision: str = "f32"):
     """Deduplicated, VMEM-fitting candidate pairs for the timed sweep.
 
-    Oversized pairs are excluded *statically*: on TPU they would fail at
-    Mosaic compile time (wasting a sweep slot), and in interpret mode
-    they would time fine and poison the cache with a config that OOMs on
-    hardware.
+    Two pairs that run the same tiles are one candidate: where the square
+    kernel runs, block_m does not reach it. Oversized pairs are excluded
+    *statically*: on TPU they would fail at Mosaic compile time (wasting
+    a sweep slot), and in interpret mode they would time fine and poison
+    the cache with a config that OOMs on hardware.
     """
     seen, pairs = set(), []
     for bn in CANDIDATE_BLOCKS:
         for bm in CANDIDATE_BLOCKS:
-            eff = effective_blocks(n, m, bn, bm, precision)[:2]
-            if eff in seen:
+            plan = square_plan(n, m, B, bn, precision)
+            tiles = plan or effective_blocks(n, m, bn, bm, precision)[:2]
+            if tiles in seen:
                 continue
-            seen.add(eff)
-            if fused_vmem_breakdown(n, m, bn, bm, precision).fits():
+            seen.add(tiles)
+            if (plan is not None
+                    or fused_vmem_breakdown(n, m, bn, bm, precision).fits()):
                 pairs.append((bn, bm))
     return pairs
 
@@ -159,7 +166,7 @@ def autotune_blocks(n: int, m: int, B: int = 1, *, precision: str = "f32",
     scale = max(1.0, float(np.max(np.abs(ref))))
 
     best, best_t = None, float("inf")
-    for bn, bm in _candidate_pairs(nb, mb, precision):
+    for bn, bm in _candidate_pairs(nb, mb, Bb, precision):
         def run(K1, K2, mask, u, _bn=bn, _bm=bm):
             return lk_mvm_fused(K1, K2, mask, u, 0.1, block_n=_bn,
                                 block_m=_bm, precision=precision,

@@ -21,7 +21,8 @@ from repro.analysis import (analyze_file, analyze_paths, analyze_source,
 from repro.analysis.rules import RULES_BY_ID
 from repro.analysis.vmem import (VMEM_BUDGET_BYTES, VmemBudgetError,
                                  audit_candidate_space, best_fitting_blocks,
-                                 check_fused_blocks, fused_vmem_breakdown)
+                                 check_fused_blocks, fused_vmem_breakdown,
+                                 square_plan, square_vmem_breakdown)
 
 HERE = os.path.dirname(__file__)
 FIXTURES = os.path.join(HERE, "analysis_fixtures")
@@ -215,6 +216,52 @@ def test_autotune_blocks_vmem_filtered():
         assert fused_vmem_breakdown(512, 512, *blocks).fits()
     finally:
         clear_cache()
+
+
+def test_square_vmem_breakdown_matches_blockspecs_at_lcbench():
+    """At (n, m, B) = (2000, 52, 65) the square kernel's model is its
+    BlockSpecs byte for byte: f32 blocks, m padded to 56 sublanes, lanes
+    to 128; the heuristic's chunk fits 16 MiB and meets the kernel's
+    shape targets."""
+    from repro.kernels.autotune import _heuristic
+
+    block_n, _ = _heuristic(2000, 52)
+    plan = square_plan(2000, 52, 65, block_n)
+    assert (plan.bk, plan.tn, plan.m8, plan.n_pad) == (256, 2048, 56, 2048)
+    c = plan.chunk
+    bd = square_vmem_breakdown(plan)
+    f32 = 4
+    assert bd.um_block == c * 56 * 256 * f32          # (c, 56, 256) um^T
+    assert bd.k2t == 56 * 128 * f32                   # (56, 56) K2^T
+    assert bd.k1_block == 256 * 2048 * f32            # (256, 2048) K1^T
+    assert bd.mask_block == 56 * 2048 * f32           # (56, 2048) mask^T
+    assert bd.out_block == c * 56 * 2048 * f32        # (c, 56, 2048) out
+    assert bd.t_scratch == c * 56 * 256 * f32         # (56 c, 256) scratch
+    assert bd.product == c * 56 * 512 * f32           # (56 c, 512) product
+    assert bd.total == (2 * (bd.um_block + bd.k2t + bd.k1_block
+                             + bd.mask_block + bd.out_block)
+                        + bd.t_scratch + bd.product + bd.temporaries)
+    assert bd.fits() and bd.total <= VMEM_BUDGET_BYTES
+    gc, gj, gk = plan.grid(65)
+    assert gc * gj * gk <= 200                        # grid steps a call
+    assert plan.tn >= 128 and plan.ts >= 128          # the product's lanes
+    assert plan.m8 / plan.tn <= 0.1                   # stage R vs main
+    assert gc * c >= 65 and gc * c - 65 < c           # chunks cover B once
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_square_plan_fits_budget_and_falls_back(precision):
+    """Every plan the chooser returns fits the budget; where K2 alone is
+    too wide it returns None and the row-strip kernel takes the shape."""
+    for n, m in [(8, 8), (100, 40), (2000, 52), (3125, 200), (8192, 64),
+                 (1000, 500)]:
+        for B in (1, 17, 65):
+            plan = square_plan(n, m, B, 256, precision)
+            assert plan is not None, (n, m, B)
+            assert square_vmem_breakdown(plan, precision).fits()
+            assert plan.n_pad >= n and plan.n_pad % plan.tn == 0
+            assert plan.tn % plan.bk == 0 and plan.tn % plan.ts == 0
+    assert square_plan(512, 8192, 1, 256, precision) is None
 
 
 def test_lk_mvm_op_falls_back_to_two_stage():

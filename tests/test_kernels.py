@@ -9,7 +9,9 @@ from hypothesis import strategies as st
 from repro.kernels import (CANDIDATE_BLOCKS, autotune_blocks, lk_mvm_fused,
                            lk_mvm_pallas, lk_mvm_ref, lk_mvm_two_stage,
                            rbf_gram_pallas, rbf_gram_ref)
+from repro.analysis.vmem import SquarePlan, square_plan
 from repro.kernels import autotune as kernel_autotune
+from repro.kernels import lk_mvm
 
 SHAPES_MVM = [
     # (B, n, m)
@@ -168,6 +170,68 @@ def test_lk_mvm_fused_leading_batch_dims():
     assert out.shape == (2, 3, 16, 12)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)
+
+
+# --------------------------------------------------------------------------
+# square fused kernel: transposed layout, a chunk of right-hand sides a step
+# --------------------------------------------------------------------------
+def _gappy_problem(B, n, m, seed=0):
+    """Like _mvm_problem, but whole rows (configurations with no epoch) and
+    the last columns (epochs no configuration reached) are unobserved."""
+    K1, K2, _, _ = _mvm_problem(1, n, m, jnp.float32, seed)
+    k3, k4 = jax.random.split(jax.random.PRNGKey(seed + 1))
+    lens = jax.random.randint(k3, (n,), 0, m - 2).at[::7].set(0)
+    mask = (jnp.arange(m)[None, :] < lens[:, None]).astype(jnp.float32)
+    u = jax.random.normal(k4, (B, n, m), jnp.float32)
+    return K1, K2, mask, u
+
+
+SQUARE_CASES = [
+    # (B, n, m, (bk, tn, chunk)): n not a multiple of bk, m = 52 and 13 not
+    # multiples of 8, B not a multiple of the chunk (a partial last chunk)
+    (1, 300, 52, (128, 128, 1)),
+    (17, 300, 52, (128, 384, 8)),
+    (17, 200, 13, (128, 128, 5)),
+    (65, 300, 52, (128, 128, 8)),
+    (65, 200, 13, (128, 256, 13)),
+]
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+@pytest.mark.parametrize("B,n,m,tiles", SQUARE_CASES)
+def test_square_fused_kernel_matches_ref(B, n, m, tiles, precision):
+    """The square path against the oracle, at the tiles given, on a mask
+    with unobserved rows and columns; f32 at 1e-5, bf16 at bf16 level."""
+    bk, tn, chunk = tiles
+    m8 = -(-m // (8 if precision == "f32" else 16)) * (
+        8 if precision == "f32" else 16)
+    plan = SquarePlan(bk=bk, tn=tn, chunk=chunk, m8=m8,
+                      n_pad=-(-n // tn) * tn)
+    K1, K2, mask, u = _gappy_problem(B, n, m)
+    out = np.asarray(lk_mvm._square_call(K1, K2, mask, u, 0.29, plan=plan,
+                                         precision=precision,
+                                         interpret=True))
+    ref = np.asarray(lk_mvm_ref(K1, K2, mask, u, 0.29))
+    assert out.shape == (B, n, m) and out.dtype == np.float32
+    if precision == "f32":
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    else:
+        np.testing.assert_allclose(out, ref, atol=0.05 * np.max(np.abs(ref)))
+    np.testing.assert_array_equal(out * (1 - np.asarray(mask)), 0)
+
+
+@pytest.mark.parametrize("B", [1, 17, 65])
+def test_lk_mvm_fused_square_path_matches_ref(B):
+    """The public entry point at the plan it derives from B takes the
+    square layout and agrees with the oracle at 1e-5."""
+    n, m = 150, 52
+    assert square_plan(n, m, B, 128) is not None
+    K1, K2, mask, u = _gappy_problem(B, n, m, seed=B)
+    out = lk_mvm_fused(K1, K2, mask, u, 0.41, block_n=128, block_m=64,
+                       interpret=True)
+    ref = lk_mvm_ref(K1, K2, mask, u, 0.41)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
+                               atol=1e-5)
 
 
 def test_autotune_blocks_heuristic_and_cache():

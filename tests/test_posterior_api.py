@@ -139,6 +139,32 @@ def test_batched_cache_semantics(batched_state):
     assert m2 is m1 and v2 is v1       # resident default final
 
 
+@pytest.mark.parametrize("batched", [False, True])
+def test_cached_posterior_goes_with_its_state(task, batched_state, batched):
+    """A state and the posterior it caches form no reference cycle: with
+    the cyclic garbage collector off, dropping the state frees the
+    posterior and its solves (a stream of rounds used to keep every
+    round's device arrays until the collector ran)."""
+    import gc
+    import weakref
+
+    if batched:
+        st = stack_states(unstack(batched_state))
+        post = posterior_batch(st)
+    else:
+        st = fit(task.X, task.t, task.Y, task.mask, CFG)
+        post = posterior(st)
+    post.final()
+    assert (posterior_batch(st) if batched else posterior(st)) is post
+    gone = weakref.ref(post)
+    gc.disable()
+    try:
+        del st, post
+        assert gone() is None
+    finally:
+        gc.enable()
+
+
 def test_extend_invalidates_cache_and_changes_prediction(task):
     st = fit(task.X, task.t, task.Y, task.mask, CFG)
     p1 = posterior(st)

@@ -105,19 +105,45 @@ def test_pallas_engine_mll_value_and_grad_compiles(one_chip, monkeypatch):
                    _spec(one_chip, cfg.slq_probes, N, M))
 
 
-def test_fused_kernel_keeps_its_hlo_name_under_any_jit(one_chip):
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+@pytest.mark.parametrize("batch", [17, 65])
+def test_square_fused_path_compiles_per_batch(one_chip, batch, precision):
+    """The square fused path at the fit's MLL solves (17 right-hand sides)
+    and the posterior solve (65): one Mosaic call whose output keeps the
+    stack first, in the transposed, sublane-padded layout."""
+    import re
+
+    m8 = 56 if precision == "f32" else 64
+    text = jax.jit(lambda K1, K2, mask, u: lk_mvm_fused(
+        K1, K2, mask, u, 0.1, block_n=256, block_m=64, precision=precision,
+        interpret=False)).lower(
+        _spec(one_chip, N, N), _spec(one_chip, M, M), _spec(one_chip, N, M),
+        _spec(one_chip, batch, N, M)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and " = " in ln]
+    assert len(calls) == 1
+    assert re.search(rf"lk_mvm_fused\S* = f32\[{batch},{m8},2048\]",
+                     calls[0])
+
+
+def test_fused_kernel_keeps_its_hlo_name_under_any_jit(one_chip, monkeypatch):
     """The fused call is named ``lk_mvm_fused`` in the compiled program
     even when a jit of another name (a whole solve loop, say) wraps it
-    directly: the benchmark's roofline reader matches that name."""
+    directly: the benchmark's roofline reader matches that name. The loop
+    runs the posterior's path, the pallas engine's MVM at 65 stacked
+    right-hand sides; the reader takes B from the output's first
+    dimension."""
     import re
+
+    from repro.core.engines import _pallas_mvm_kw
+
+    monkeypatch.setattr(lk_mvm, "resolve_interpret", lambda interpret: False)
 
     def cg_like_loop(K1, K2, mask, u):
         def body(c):
             i, x = c
-            y = lk_mvm._fused_call(K1, K2, mask, x, mask * x, 0.1,
-                                   block_n=128, block_m=128,
-                                   precision="f32", interpret=False)
-            return i + 1, x + 1e-3 * y[:, :N, :M]
+            y = _pallas_mvm_kw(K1, K2, mask, x, noise=0.1)
+            return i + 1, x + 1e-3 * y
         return jax.lax.while_loop(lambda c: c[0] < 3, body, (0, u))[1]
 
     text = jax.jit(cg_like_loop).lower(
@@ -127,4 +153,4 @@ def test_fused_kernel_keeps_its_hlo_name_under_any_jit(one_chip):
              and " = " in ln]
     assert calls and all(
         re.search(r"lk_mvm_fused\S* = .*tpu_custom_call", ln) for ln in calls)
-    assert re.search(r" = f32\[65,\d+,52\]", calls[0])  # B read from it
+    assert re.search(r" = f32\[65,\d+,\d+\]", calls[0])  # B read from it
