@@ -16,7 +16,7 @@ lives or dies. Four invariants:
 * **full-precision f32 contractions** — every f32 ``dot_general`` in the
   fit objective, the polish program and the posteriors asks for
   ``Precision.HIGHEST``. XLA's default on TPU is one bf16 pass, which the
-  Gram's distance expansion and the CG residuals cannot survive; on the
+  Gram's derivative and the CG residuals cannot survive; on the
   CPU the default is already exact, so only this audit sees a missing
   flag before the chip does.
 * **retrace-free refits** — two ``refit`` rounds on same-shaped data must

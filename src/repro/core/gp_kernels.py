@@ -24,24 +24,51 @@ __all__ = [
     "KERNELS_1D",
 ]
 
-# Every f32 contraction in the GP numerics (Gram build, MVM, posterior
-# products, L-BFGS inner products) asks for full f32 precision. XLA's
-# default on TPU rounds f32 matmul operands to bf16 for one MXU pass, which
-# wrecks the distance expansion below (cancellation at ~3 digits) and
-# floors CG residuals near 1e-3. On the CPU the setting changes nothing.
+# Every f32 contraction in the GP numerics (MVM, posterior products, the
+# Gram's derivative, L-BFGS inner products) asks for full f32 precision.
+# XLA's default on TPU rounds f32 matmul operands to bf16 for one MXU pass,
+# which floors CG residuals near 1e-3. On the CPU the setting changes
+# nothing.
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
+@jax.custom_jvp
 def sq_dist(x1: jnp.ndarray, x2: jnp.ndarray) -> jnp.ndarray:
-    """Pairwise squared Euclidean distance.
+    """Pairwise squared Euclidean distance by direct differences.
 
-    x1: (n, d), x2: (p, d) -> (n, p). Uses the matmul expansion so the
-    contraction runs on the MXU; clamps tiny negatives from cancellation.
+    x1: (n, d), x2: (p, d) -> (n, p) = sum_k (x1_ik - x2_jk)^2, accumulated
+    one feature at a time into one (n, p) buffer. The sum is unrolled over
+    d inside one jit, so XLA fuses it into a single elementwise pass, also
+    when called eagerly: no (n, p, d) array exists. The matmul expansion
+    |x|^2 + |x'|^2 - 2 x.x' cancels in f32 once the scaled inputs are large
+    (a small lengthscale), leaving an indefinite Gram; differences keep
+    every entry exact to rounding and >= 0.
+
+    The derivative is analytic (below), so autodiff saves only x1 and x2,
+    never a per-feature residual.
     """
-    n1 = jnp.sum(x1 * x1, axis=-1)[:, None]
-    n2 = jnp.sum(x2 * x2, axis=-1)[None, :]
-    d2 = n1 + n2 - 2.0 * jnp.matmul(x1, x2.T, precision=HIGHEST)
-    return jnp.maximum(d2, 0.0)
+    return _sq_dist_sum(x1, x2)
+
+
+@jax.jit
+def _sq_dist_sum(x1, x2):
+    d2 = jnp.zeros((x1.shape[0], x2.shape[0]),
+                   jnp.result_type(x1.dtype, x2.dtype))
+    for k in range(x1.shape[1]):
+        d2 = d2 + jnp.square(x1[:, k, None] - x2[None, :, k])
+    return d2
+
+
+@sq_dist.defjvp
+def _sq_dist_jvp(primals, tangents):
+    """d(d2)_ij = 2 sum_k (x1_ik - x2_jk)(dx1_ik - dx2_jk), as contractions
+    (linear in the tangents, so its transpose is the VJP)."""
+    x1, x2 = primals
+    dx1, dx2 = tangents
+    mm = lambda a, b: jnp.matmul(a, b.T, precision=HIGHEST)  # noqa: E731
+    dd = (jnp.sum(x1 * dx1, axis=-1)[:, None]
+          + jnp.sum(x2 * dx2, axis=-1)[None, :] - mm(dx1, x2) - mm(x1, dx2))
+    return _sq_dist_sum(x1, x2), 2.0 * dd
 
 
 def abs_dist(t1: jnp.ndarray, t2: jnp.ndarray) -> jnp.ndarray:

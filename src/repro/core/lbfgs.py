@@ -56,14 +56,16 @@ def _two_loop(g, s_list, y_list):
     return q
 
 
-def _wolfe_line_search(fg, x, f0, g0, d, c1=1e-4, c2=0.9, max_evals=25):
-    """Strong-Wolfe line search (bracket + zoom, Nocedal & Wright alg. 3.5/3.6)."""
+def _wolfe_line_search(fg, x, f0, g0, d, c1=1e-4, c2=0.9, max_evals=25,
+                       lower=None):
+    """Strong-Wolfe line search (bracket + zoom, Nocedal & Wright alg. 3.5/3.6)
+    along ``x + a d``, clipped onto ``lower`` when that is given."""
     dg0 = float(np.dot(g0, d))
     if dg0 >= 0:  # not a descent direction; caller resets
         return None, 0
 
     def phi(a):
-        f, g = fg(x + a * d)
+        f, g = fg(_project(x + a * d, lower))
         return float(f), g, float(np.dot(g, d))
 
     evals = 0
@@ -116,16 +118,33 @@ def _wolfe_line_search(fg, x, f0, g0, d, c1=1e-4, c2=0.9, max_evals=25):
     return best, evals  # best finite decrease seen, or None (caller resets)
 
 
+def _project(x, lower):
+    return x if lower is None else np.maximum(x, lower)
+
+
+def _free(x, v, lower):
+    """``v`` with the coordinates that would leave the box ``x >= lower``
+    zeroed (a coordinate at its bound moving down stays put)."""
+    return v if lower is None else np.where((x <= lower) & (v < 0), 0.0, v)
+
+
 def lbfgs_minimize(value_and_grad: Callable, x0, max_iters: int = 100,
                    history: int = 10, gtol: float = 1e-6,
-                   ftol: float = 1e-10) -> LBFGSResult:
-    """Minimise a smooth objective. ``value_and_grad(x) -> (f, g)``."""
+                   ftol: float = 1e-10, lower=None) -> LBFGSResult:
+    """Minimise a smooth objective. ``value_and_grad(x) -> (f, g)``.
+
+    ``lower`` (``-inf`` where free) bounds the iterate from below by
+    projection: ``x0`` and every trial point are clipped onto it, and a
+    coordinate at its bound whose step points out of the box stays put.
+    """
 
     def fg(x):
         f, g = value_and_grad(jnp.asarray(x))
         return float(f), np.asarray(g, dtype=np.float64)
 
-    x = np.asarray(x0, dtype=np.float64).copy()
+    if lower is not None:
+        lower = np.asarray(lower, dtype=np.float64)
+    x = _project(np.asarray(x0, dtype=np.float64).copy(), lower)
     f, g = fg(x)
     n_evals = 1
     s_list: list[np.ndarray] = []
@@ -133,22 +152,22 @@ def lbfgs_minimize(value_and_grad: Callable, x0, max_iters: int = 100,
     converged = False
     it = 0
     for it in range(1, max_iters + 1):
-        if np.max(np.abs(g)) < gtol:
+        if np.max(np.abs(_free(x, -g, lower))) < gtol:
             converged = True
             break
-        d = -_two_loop(g, s_list, y_list)
-        res, ev = _wolfe_line_search(fg, x, f, g, d)
+        d = _free(x, -_two_loop(g, s_list, y_list), lower)
+        res, ev = _wolfe_line_search(fg, x, f, g, d, lower=lower)
         n_evals += ev
         if res is None:  # bad direction: reset memory, steepest descent
             s_list.clear()
             y_list.clear()
-            d = -g
-            res, ev = _wolfe_line_search(fg, x, f, g, d)
+            d = _free(x, -g, lower)
+            res, ev = _wolfe_line_search(fg, x, f, g, d, lower=lower)
             n_evals += ev
             if res is None:
                 break
         a, f_new, g_new = res
-        x_new = x + a * d
+        x_new = _project(x + a * d, lower)
         s = x_new - x
         y = g_new - g
         if float(np.dot(s, y)) > 1e-10 * float(np.linalg.norm(s)) * float(np.linalg.norm(y)):
@@ -166,6 +185,6 @@ def lbfgs_minimize(value_and_grad: Callable, x0, max_iters: int = 100,
     # used to report converged=False (the gtol check only ran at the TOP of
     # each iteration), making a capped-but-converged run indistinguishable
     # from a genuinely budget-limited one. Check the final iterate too.
-    if not converged and np.max(np.abs(g)) < gtol:
+    if not converged and np.max(np.abs(_free(x, -g, lower))) < gtol:
         converged = True
     return LBFGSResult(x=x, fun=f, n_iters=it, n_evals=n_evals, converged=converged)

@@ -27,6 +27,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from . import telemetry
 from .gp_kernels import HIGHEST
 from .mvm import lk_operator
 from .solvers import get_solver
@@ -35,7 +36,14 @@ __all__ = ["sample_posterior_grid", "prior_residual_draws",
            "kronecker_correction"]
 
 
-def _psd_cholesky(K: jnp.ndarray, jitter: float) -> jnp.ndarray:
+#: Tenfold jitter escalations tried when a Cholesky factor is not finite.
+CHOLESKY_RETRIES = 3
+
+_all_finite = jax.jit(lambda a: jnp.all(jnp.isfinite(a)))
+
+
+def _psd_cholesky(K: jnp.ndarray, jitter,
+                  retries: int = CHOLESKY_RETRIES) -> jnp.ndarray:
     """Cholesky of a Gram matrix that is PSD in exact arithmetic.
 
     The diagonal jitter is at least the rounding noise of K's dtype, n * eps
@@ -43,11 +51,23 @@ def _psd_cholesky(K: jnp.ndarray, jitter: float) -> jnp.ndarray:
     eigenvalues far below f32 rounding, and an f32 Cholesky at the f64-sized
     default jitter returns NaN. In f64 the floor is orders of magnitude
     below the default, so f64 draws are unchanged.
+
+    That floor is about eps * ||K||, where a factorisation's own rounding
+    lies: after refits LCBench's K1 (lengthscales ~10, least f32
+    eigenvalue below -floor) factored to NaN on a TPU. So an eager factor
+    is read on the host once, and one that is not finite is taken again at
+    ten times the jitter, up to ``retries`` times (as GPyTorch's
+    ``psd_safe_cholesky``). A finite factor is returned as it was; under a
+    trace the factor is returned unread.
     """
     n = K.shape[0]
     floor = n * jnp.finfo(K.dtype).eps * jnp.max(jnp.diag(K))
     eps = jnp.maximum(jnp.asarray(jitter, K.dtype), floor)
-    return jnp.linalg.cholesky(K + eps * jnp.eye(n, dtype=K.dtype))
+    L = jnp.linalg.cholesky(K + eps * jnp.eye(n, dtype=K.dtype))
+    if (retries == 0 or isinstance(L, jax.core.Tracer)
+            or telemetry.host_read(_all_finite(L), "matheron.factor")):
+        return L
+    return _psd_cholesky(K, 10.0 * eps, retries - 1)
 
 
 def prior_residual_draws(key, K1_joint: jnp.ndarray, K2: jnp.ndarray,

@@ -84,7 +84,8 @@ def _two_loop(g, S, Yb, rho, valid):
 
 
 def make_polish(vg: Callable, steps: int, history: int = 5,
-                c1: float = 1e-4, n_backtracks: int = 4) -> Callable:
+                c1: float = 1e-4, n_backtracks: int = 4,
+                lower=None) -> Callable:
     """Build ``polish(x0, *args) -> PolishResult`` running ``steps`` L-BFGS
     steps of the objective whose value-and-gradient is ``vg(x, *args)``.
 
@@ -95,6 +96,11 @@ def make_polish(vg: Callable, steps: int, history: int = 5,
     put (that step is spent, keeping cost fixed). The returned function
     is pure and fixed-shape: jit it once and dispatch it per task (see
     module docstring for why batched lowerings are avoided).
+
+    ``lower`` ((P,) host array, ``-inf`` where free) bounds the iterate
+    from below by projection: ``x0`` and every candidate are clipped onto
+    it, a coordinate at its bound whose direction points out of the box
+    stays put, and ``grad_inf`` is that of the projected gradient.
     """
     if steps < 1:
         raise ValueError(f"make_polish needs steps >= 1, got {steps}")
@@ -104,6 +110,14 @@ def make_polish(vg: Callable, steps: int, history: int = 5,
         dtype = x0.dtype
         P = x0.shape[0]
         alphas = jnp.asarray(ladder, dtype)
+        lo = jnp.full((P,), -jnp.inf, dtype) if lower is None \
+            else jnp.asarray(lower, dtype)
+
+        def free(x, v):
+            """``v`` with the coordinates that would leave the box zeroed."""
+            return jnp.where((x <= lo) & (v < 0), 0.0, v)
+
+        x0 = jnp.maximum(x0, lo)
         f0, g0 = vg(x0, *args)
         S0 = jnp.zeros((history, P), dtype)
         Y0 = jnp.zeros((history, P), dtype)
@@ -112,18 +126,20 @@ def make_polish(vg: Callable, steps: int, history: int = 5,
 
         def step(carry, _):
             x, f, g, S, Yb, rho, valid, n_acc = carry
-            d = -_two_loop(g, S, Yb, rho, valid)
+            d = free(x, -_two_loop(g, S, Yb, rho, valid))
             dg = jnp.dot(d, g, precision=HIGHEST)
             descent = dg < 0
-            d = jnp.where(descent, d, -g)
-            dg = jnp.where(descent, dg, -jnp.dot(g, g, precision=HIGHEST))
+            pg = free(x, -g)
+            d = jnp.where(descent, d, pg)
+            dg = jnp.where(descent, dg, -jnp.dot(pg, pg, precision=HIGHEST))
 
-            cand = jax.lax.map(lambda a: vg(x + a * d, *args), alphas)
+            cand = jax.lax.map(
+                lambda a: vg(jnp.maximum(x + a * d, lo), *args), alphas)
             fs, gs = cand
             ok = jnp.isfinite(fs) & (fs <= f + c1 * alphas * dg)
             any_ok = jnp.any(ok)
             j = jnp.argmax(ok)                   # first passing candidate
-            x_new = jnp.where(any_ok, x + alphas[j] * d, x)
+            x_new = jnp.where(any_ok, jnp.maximum(x + alphas[j] * d, lo), x)
             f_new = jnp.where(any_ok, fs[j], f)
             g_new = jnp.where(any_ok, gs[j], g)
 
@@ -144,7 +160,7 @@ def make_polish(vg: Callable, steps: int, history: int = 5,
 
         init = (x0, f0, g0, S0, Y0, rho0, valid0, jnp.asarray(0, jnp.int32))
         (x, f, g, *_, n_acc), _ = jax.lax.scan(step, init, None, length=steps)
-        return PolishResult(x=x, fun=f, grad_inf=jnp.max(jnp.abs(g)),
+        return PolishResult(x=x, fun=f, grad_inf=jnp.max(jnp.abs(free(x, -g))),
                             n_accepted=n_acc)
 
     return polish

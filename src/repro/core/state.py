@@ -36,7 +36,8 @@ from .caching import LRUCache
 from .errors import ObservationError, check_grid_columns, check_observed_finite
 from .lbfgs import lbfgs_minimize
 from .polish import make_polish
-from .priors import noise_prior_logpdf, x_lengthscale_prior_logpdf
+from .priors import (RAW_NOISE_FLOOR, noise_prior_logpdf,
+                     x_lengthscale_prior_logpdf)
 from .slq import rademacher_probes
 from .transforms import TTransform, XTransform, YTransform
 
@@ -101,10 +102,12 @@ class LKGPConfig:
     # starting point on every fit AND every refit. ``polish_steps`` picks
     # the optimiser: -1 (default) runs the host-driven L-BFGS for up to
     # ``lbfgs_iters`` iterations; 0 skips optimisation entirely (the init
-    # IS the fit — params round-trip bitwise); k > 0 runs the fixed-budget
-    # pure-JAX polish (:mod:`repro.core.polish`) for exactly k L-BFGS steps
-    # in ONE jitted call. Neither field enters the traced objective, so
-    # flipping them never retraces (_objective_cache_key excludes both).
+    # IS the fit — params round-trip bitwise above the noise floor); k > 0
+    # runs the fixed-budget pure-JAX polish (:mod:`repro.core.polish`) for
+    # exactly k L-BFGS steps in ONE jitted call. Every path keeps
+    # exp(raw_noise) >= priors.NOISE_FLOOR by projection. Neither field
+    # enters the traced objective, so flipping them never retraces
+    # (_objective_cache_key excludes both).
     hyper_init: str = "default"     # "default" | "amortized"
     polish_steps: int = -1          # -1 host L-BFGS | 0 no-op | k device steps
     posterior_samples: int = 64
@@ -327,6 +330,21 @@ def _objective_cache_key(cfg: LKGPConfig) -> tuple:
             cfg.slq_iters, cfg.slq_via_cg, cfg.jitter, cfg.use_pallas)
 
 
+def _lower_bounds(d: int, batch: int = 1) -> np.ndarray:
+    """Lower bounds of the flat parameters of ``batch`` tasks, raveled field
+    by field (``raw_noise`` last): ``-inf`` but for the noise floor."""
+    return np.r_[np.full(batch * (d + 2), -np.inf),
+                 np.full(batch, RAW_NOISE_FLOOR)]
+
+
+def _count_noise_floor(raw_noise: np.ndarray) -> None:
+    """Count the fits whose returned noise sits at the floor, from the
+    parameters the optimiser already handed to the host."""
+    at = int(np.sum(np.asarray(raw_noise) <= RAW_NOISE_FLOOR))
+    if at:
+        telemetry.count("fit.noise_floor", at)
+
+
 def _cached_fit_vg(cfg: LKGPConfig, engine, d: int):
     """value_and_grad of the fit objective as a pure jitted function.
 
@@ -377,7 +395,8 @@ def _cached_polish(cfg: LKGPConfig, engine, d: int, steps: int):
             return f, _flatten_params(g)
 
         fn = jax.jit(make_polish(vg_flat, steps=steps,
-                                 n_backtracks=_POLISH_BACKTRACKS))
+                                 n_backtracks=_POLISH_BACKTRACKS,
+                                 lower=_lower_bounds(d)))
         _POLISH_CACHE[key] = fn
     return fn
 
@@ -437,19 +456,23 @@ def _polish_fit(cfg: LKGPConfig, engine, d: int, dtype, budget: int,
     """Fixed-budget polish (or the ``budget == 0`` no-op) for ``fit``."""
     flat0 = _flatten_params(p0).astype(dtype)
     if budget == 0:
+        p0 = p0._replace(raw_noise=jnp.maximum(
+            p0.raw_noise, jnp.asarray(RAW_NOISE_FLOOR, dtype)))
         f0, _ = _cached_fit_vg(cfg, engine, d)(p0, Xn, tn, Yn, mask, probes)
-        res = FitResult(x=np.asarray(flat0), fun=float(f0), n_iters=0,
-                        n_evals=1, converged=False, budget=0,
+        res = FitResult(x=np.asarray(_flatten_params(p0)), fun=float(f0),
+                        n_iters=0, n_evals=1, converged=False, budget=0,
                         init_source=init_source, optimizer="none")
         return p0, res
     pol = _cached_polish(cfg, engine, d, budget)
-    pr = pol(flat0, Xn, tn, Yn, mask, probes)
+    with telemetry.span("state.polish"):
+        pr = pol(flat0, Xn, tn, Yn, mask, probes)
+        res = FitResult(x=np.asarray(pr.x), fun=float(pr.fun),
+                        n_iters=budget,
+                        n_evals=1 + budget * _POLISH_BACKTRACKS,
+                        converged=bool(pr.grad_inf < _POLISH_GTOL),
+                        budget=budget, init_source=init_source,
+                        optimizer="polish")
     params = _unflatten_params(jnp.asarray(pr.x), d)
-    res = FitResult(x=np.asarray(pr.x), fun=float(pr.fun), n_iters=budget,
-                    n_evals=1 + budget * _POLISH_BACKTRACKS,
-                    converged=bool(pr.grad_inf < _POLISH_GTOL),
-                    budget=budget, init_source=init_source,
-                    optimizer="polish")
     return params, res
 
 
@@ -526,12 +549,14 @@ def fit(X, t, Y, mask, config: LKGPConfig | None = None,
             return f, jax.flatten_util.ravel_pytree(g)[0]
 
         lb = lbfgs_minimize(value_and_grad, np.asarray(flat0, np.float64),
-                            max_iters=cfg.lbfgs_iters)
+                            max_iters=cfg.lbfgs_iters,
+                            lower=_lower_bounds(d))
         params = unravel(jnp.asarray(lb.x, dtype))
         res = FitResult(x=lb.x, fun=lb.fun, n_iters=lb.n_iters,
                         n_evals=lb.n_evals, converged=lb.converged,
                         budget=cfg.lbfgs_iters, init_source=init_source,
                         optimizer="lbfgs")
+    _count_noise_floor(res.x[-1])       # raw_noise is raveled last
     state = LKGPState(params=params, X=X, t=t, Y=Y, mask=mask,
                       x_tf=x_tf, t_tf=t_tf, y_tf=y_tf, config=cfg)
     object.__setattr__(state, "fit_result", res)
@@ -620,6 +645,8 @@ def fit_batch(X, t, Y, mask, config: LKGPConfig | None = None,
         engine = get_engine("dense")
         flat0 = jax.vmap(_flatten_params)(p0).astype(dtype)
         if budget == 0:
+            flat0 = flat0.at[:, -1].max(RAW_NOISE_FLOOR)
+            p0 = p0._replace(raw_noise=flat0[:, -1])
             vg = _cached_fit_vg(cfg, engine, d)
             fs = [vg(_unflatten_params(flat0[i], d), Xn[i], tn[i], Yn[i],
                      mask[i], None)[0] for i in range(B)]
@@ -659,12 +686,15 @@ def fit_batch(X, t, Y, mask, config: LKGPConfig | None = None,
             return f, jax.flatten_util.ravel_pytree(g)[0]
 
         lb = lbfgs_minimize(value_and_grad, np.asarray(flat0, np.float64),
-                            max_iters=cfg.lbfgs_iters)
+                            max_iters=cfg.lbfgs_iters,
+                            lower=_lower_bounds(d, B))
         params = unravel(jnp.asarray(lb.x, dtype))
         res = FitResult(x=lb.x, fun=lb.fun, n_iters=lb.n_iters,
                         n_evals=lb.n_evals, converged=lb.converged,
                         budget=cfg.lbfgs_iters, init_source=init_source,
                         optimizer="lbfgs")
+    _count_noise_floor(res.x[-B:] if res.optimizer == "lbfgs"
+                       else res.x[:, -1])
     state = LKGPState(params=params, X=X, t=t, Y=Y, mask=mask,
                       x_tf=x_tf, t_tf=t_tf, y_tf=y_tf, config=cfg)
     object.__setattr__(state, "fit_result", res)
