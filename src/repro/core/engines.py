@@ -221,6 +221,7 @@ class DenseEngine:
 # --------------------------------------------------------------------------
 # iterative (CG + SLQ)
 # --------------------------------------------------------------------------
+@jax.tree_util.register_pytree_node_class
 class LatentKroneckerOperator:
     """Callable A(u) that remembers its Kronecker factors.
 
@@ -228,6 +229,11 @@ class LatentKroneckerOperator:
     that ``solve`` can build the pivoted-Cholesky preconditioner from the
     factors when ``LKGPConfig.precond_rank > 0`` — the factorisation only
     needs K1 / K2 / mask, never the assembled operator.
+
+    A pytree: the factors are its children and the MVM its static part, so
+    an eager CG solve passes the operator to one program compiled per
+    shape (:mod:`repro.core.solvers.cg`). The preconditioner cache and
+    ``last_result`` stay with the instance and off the pytree.
     """
 
     def __init__(self, K1, K2, mask, noise, mvm=lk_mvm):
@@ -237,6 +243,13 @@ class LatentKroneckerOperator:
 
     def __call__(self, u):
         return self._mvm(self.K1, self.K2, self.mask, u, noise=self.noise)
+
+    def tree_flatten(self):
+        return (self.K1, self.K2, self.mask, self.noise), self._mvm
+
+    @classmethod
+    def tree_unflatten(cls, mvm, children):
+        return cls(*children, mvm=mvm)
 
     def preconditioner(self, rank: int):
         """Woodbury M^{-1} from the rank-``rank`` pivoted Cholesky, cached.

@@ -25,8 +25,7 @@ Section 2 of the paper.
 """
 from __future__ import annotations
 
-from functools import partial
-
+import jax
 import jax.numpy as jnp
 
 from .gp_kernels import HIGHEST
@@ -56,8 +55,9 @@ def lk_mvm(K1: jnp.ndarray, K2: jnp.ndarray, mask: jnp.ndarray,
 
 
 def lk_operator(K1, K2, mask, noise):
-    """Partial application returning ``A(u)`` for the CG solver."""
-    return partial(lk_mvm, K1, K2, mask, noise=noise)
+    """Partial application returning ``A(u)`` for the CG solver; a pytree
+    over the factors, so an eager CG solve compiles once per shape."""
+    return jax.tree_util.Partial(lk_mvm, K1, K2, mask, noise=noise)
 
 
 def grid_to_packed(grid: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:

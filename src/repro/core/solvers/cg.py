@@ -26,6 +26,15 @@ loop the solver adds:
   mBCG). This is what lets one stacked solve of ``K^{-1}[y | probes]``
   also produce the SLQ log-determinant with zero extra operator sweeps
   (see :func:`repro.core.slq.slq_logdet_from_tridiag`).
+* **one program per shape** — an eager solve over an operator that is a
+  registered pytree (``LatentKroneckerOperator``) runs the whole loop as
+  one jitted program, the operator's arrays its arguments, so a new
+  operator of the same shapes reuses the executable. An eager
+  ``lax.while_loop`` over a closure would trace, lower and compile again
+  for every operator. Bare-closure operators stay eager; inside an outer
+  trace the loop is inlined as before. Counters ``solver.compiled`` and
+  ``solver.eager`` (:mod:`repro.core.telemetry`) count eager solves by the
+  path they took.
 """
 from __future__ import annotations
 
@@ -33,6 +42,8 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from .. import telemetry
 
 __all__ = ["cg_solve", "cg_solve_tridiag", "CGResult", "CGTridiag"]
 
@@ -157,6 +168,31 @@ def _cg_loop(A: Callable, b: jnp.ndarray, tol: float, max_iters: int,
     return res, tri
 
 
+_cg_loop_compiled = jax.jit(_cg_loop,
+                            static_argnames=("tol", "max_iters", "record"))
+
+
+def eager(*values: Any) -> bool:
+    """No tracer among the leaves of ``values``: a solve over them runs
+    outside any jit trace."""
+    return not any(isinstance(v, jax.core.Tracer)
+                   for v in jax.tree_util.tree_leaves(values))
+
+
+def _run(A: Callable, b: jnp.ndarray, tol: float, max_iters: int,
+         x0: jnp.ndarray | None, record: int):
+    """``_cg_loop``, compiled once per shape where the solve is eager and
+    the operator a pytree; inlined under an outer trace."""
+    if not eager(A, b, x0, tol):
+        return _cg_loop(A, b, tol, max_iters, x0, record)
+    if jax.tree_util.treedef_is_leaf(jax.tree_util.tree_structure(A)):
+        telemetry.count("solver.eager")
+        return _cg_loop(A, b, tol, max_iters, x0, record)
+    telemetry.count("solver.compiled")
+    return _cg_loop_compiled(A, b, tol=float(tol), max_iters=int(max_iters),
+                             x0=x0, record=record)
+
+
 def cg_solve(A: Callable[[jnp.ndarray], jnp.ndarray], b: jnp.ndarray,
              tol: float = 0.01, max_iters: int = 10_000,
              x0: jnp.ndarray | None = None) -> CGResult:
@@ -166,7 +202,7 @@ def cg_solve(A: Callable[[jnp.ndarray], jnp.ndarray], b: jnp.ndarray,
     all systems share each operator sweep. Returns grid-form solutions of
     the same shape, with per-system convergence/breakdown diagnostics.
     """
-    res, _ = _cg_loop(A, b, tol, max_iters, x0, record=0)
+    res, _ = _run(A, b, tol, max_iters, x0, record=0)
     return res
 
 
@@ -187,4 +223,4 @@ def cg_solve_tridiag(A: Callable, b: jnp.ndarray, max_rank: int,
     """
     if max_rank <= 0:
         raise ValueError("max_rank must be positive for cg_solve_tridiag")
-    return _cg_loop(A, b, tol, max_iters, x0, record=int(max_rank))
+    return _run(A, b, tol, max_iters, x0, record=int(max_rank))

@@ -63,7 +63,7 @@ import numpy as np
 
 from .. import telemetry
 from .base import StackedSolveResult, Solver, get_solver, resolve_solver
-from .cg import CGResult
+from .cg import CGResult, eager
 
 __all__ = [
     "EscalationStep", "GuardedSolveError", "GuardedSolver", "SOLVE_POLICIES",
@@ -121,12 +121,11 @@ def _is_traced(value: Any) -> bool:
 def _eager(A: Any, b: Any, x0: Any) -> bool:
     """No tracer among the right-hand side, the warm start and the
     operator's factors: the solve runs eagerly, outside any jit trace."""
-    return not any(_is_traced(v) for v in (
-        b, x0, *(getattr(A, a, None) for a in _FACTOR_ATTRS)))
+    return eager(b, x0, *(getattr(A, a, None) for a in _FACTOR_ATTRS))
 
 
-def _spanned(name: str, eager: bool):
-    return telemetry.span(name) if eager else nullcontext()
+def _spanned(name: str, on: bool):
+    return telemetry.span(name) if on else nullcontext()
 
 
 def _worst_residual(res: CGResult) -> float:

@@ -13,7 +13,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from .cg import CGResult
+from .. import telemetry
+from .cg import CGResult, eager
 
 __all__ = ["pcg_solve"]
 
@@ -30,8 +31,12 @@ def pcg_solve(A: Callable, b: jnp.ndarray, M_inv: Callable,
     ``rel_residual`` is the true final residual ``||b - Ax|| / ||b||``.
     Like :func:`repro.core.solvers.cg.cg_solve` it freezes converged
     columns, flags breakdown (``pAp <= 0``) per system, and warm-starts
-    from ``x0``.
+    from ``x0``. An eager call runs eagerly (counted ``solver.eager``):
+    compiled as one program like CG's loop, the preconditioner that the
+    operator caches would be built again inside it on every call.
     """
+    if eager(A, b, x0):
+        telemetry.count("solver.eager")
     if x0 is None:
         x0 = jnp.zeros_like(b)
     b_norm = jnp.sqrt(jnp.sum(b * b, axis=-1))

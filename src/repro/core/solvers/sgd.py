@@ -34,7 +34,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from .cg import CGResult, _dot
+from .. import telemetry
+from .cg import CGResult, _dot, eager
 
 __all__ = ["sgd_solve", "estimate_lmax"]
 
@@ -79,6 +80,8 @@ def sgd_solve(A: Callable[[jnp.ndarray], jnp.ndarray], b: jnp.ndarray,
     stop counting toward ``matvecs``, and the reported ``rel_residual`` is
     the true final ``||b - A x|| / ||b||``.
     """
+    if eager(A, b, x0):
+        telemetry.count("solver.eager")
     if x0 is None:
         x0 = jnp.zeros_like(b)
     b_norm = jnp.sqrt(_dot(b, b))

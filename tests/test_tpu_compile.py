@@ -154,3 +154,29 @@ def test_fused_kernel_keeps_its_hlo_name_under_any_jit(one_chip, monkeypatch):
     assert calls and all(
         re.search(r"lk_mvm_fused\S* = .*tpu_custom_call", ln) for ln in calls)
     assert re.search(r" = f32\[65,\d+,\d+\]", calls[0])  # B read from it
+
+
+def test_compiled_solve_calls_the_kernel_in_its_while_body(one_chip,
+                                                           monkeypatch):
+    """The posterior's eager stacked solve runs as one jitted CG loop over
+    the pallas engine's operator (``core/solvers/cg.py``). Its while body
+    calls the fused kernel under the name ``lk_mvm_fused``, where the
+    roofline reader finds it."""
+    import re
+
+    from repro.core.solvers.cg import _cg_loop_compiled
+
+    monkeypatch.setattr(lk_mvm, "resolve_interpret", lambda interpret: False)
+    A = get_engine("pallas").operator_from_grams(
+        _spec(one_chip, N, N), _spec(one_chip, M, M), _spec(one_chip, N, M),
+        _spec(one_chip))
+    text = _cg_loop_compiled.lower(
+        A, _spec(one_chip, 65, N, M), tol=1e-2, max_iters=10_000, x0=None,
+        record=0).compile().as_text()
+    bodies = set(re.findall(r"\bbody=%([\w.\-]+)", text))
+    # A computation's text runs from its header at column 0 to the next.
+    blocks = {m.group(1): blk for blk in re.split(r"\n(?=\S)", text)
+              if (m := re.match(r"%([\w.\-]+) ", blk))}
+    assert bodies and any(
+        re.search(r"lk_mvm_fused\S* = f32\[65,\d+,\d+\].*tpu_custom_call",
+                  blocks.get(body, "")) for body in bodies)
