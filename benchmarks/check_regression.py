@@ -22,18 +22,12 @@ Fails (exit 1) when
 * any acceptance claim measured by ``bench_curve_pred`` is false (the LKGP
   stays within the paper's "matches a Transformer" tolerance on NLL / MAE /
   final-value rank correlation, on identical held-out suites), or
-* any acceptance claim measured by ``bench_mvm`` is false: the fused
-  single-pass kernel must keep exact f32 parity with the jnp oracle AND
-  reduce cost_analysis bytes-accessed by >= 1.5x vs the committed
-  two-stage kernel, and the consolidated stacked solve must perform
-  strictly fewer operator sweeps (and column MVMs) per MLL/posterior
-  evaluation than the separate-solve path, or
 * any acceptance claim measured by the solver-crossover mode of
   ``bench_scaling`` is false: the SGD solver must complete the largest n
   without breakdown, the SGD-vs-CG f32 posterior mean must agree to
   rel-err <= 1e-4, and every (n, solver) crossover cell must be present.
-  Wall times include compile and are machine-relative, so like ``--mvm``
-  the section gates on its acceptance booleans only, or
+  Wall times include compile and are machine-relative, so the section
+  gates on its acceptance booleans only, or
 * any acceptance claim measured by ``bench_reliability`` is false: under
   the injected-fault schedule every healthy tenant keeps availability 1.0
   with predictions bitwise equal to a fault-free control run, every bad
@@ -48,7 +42,7 @@ Fails (exit 1) when
   ``posterior()`` on an unchanged state must perform zero additional
   operator sweeps (verified via ``solve_info`` / solve-count identity).
 
-Like ``--mvm``, the serving section is machine-relative (speedup ratios
+Like ``--scaling``, the serving section is machine-relative (speedup ratios
 and deterministic cache checks), so it gates without a committed-baseline
 comparison.
 
@@ -124,7 +118,7 @@ def _check_acceptance(name: str, payload: dict, base_payload: dict,
 
 def check(baseline: dict, backends: dict | None, automl: dict | None,
           factor: float, curvepred: dict | None = None,
-          mvm: dict | None = None, serving: dict | None = None,
+          serving: dict | None = None,
           scaling: dict | None = None,
           reliability: dict | None = None) -> list[str]:
     failures = []
@@ -200,25 +194,6 @@ def check(baseline: dict, backends: dict | None, automl: dict | None,
                   f"nll {s['nll']} mae {s['mae']} rank {s['rank_corr']}"
                   + (f" (baseline nll {base_s.get('nll')} "
                      f"mae {base_s.get('mae')})" if base_s else ""))
-
-    if mvm is not None:
-        for claim, value in mvm["acceptance"].items():
-            if value:
-                print(f"ok        mvm acceptance: {claim}")
-            else:
-                failures.append(f"CLAIM FAILED mvm acceptance: {claim}")
-        for row in mvm.get("kernel", []):
-            print(f"info      mvm kernel B={row['B']} n={row['n']} "
-                  f"m={row['m']}: bytes ratio {row['bytes_ratio']:.2f}x "
-                  f"(fused {row['fused_bytes']/1e6:.2f}MB vs two-stage "
-                  f"{row['two_stage_bytes']/1e6:.2f}MB), "
-                  f"f32 err {row['max_abs_err_f32']:.1e}")
-        s = mvm.get("solve")
-        if s:
-            print(f"info      mvm solve: stacked {s['stacked']['sweeps']} "
-                  f"sweeps / {s['stacked']['column_matvecs']} col-MVMs vs "
-                  f"separate {s['separate']['sweeps']} / "
-                  f"{s['separate']['column_matvecs']}")
 
     if serving is not None:
         for claim, value in serving["acceptance"].items():
@@ -305,8 +280,6 @@ def main(argv=None) -> int:
                     help="BENCH_automl json to gate (omit to skip)")
     ap.add_argument("--curvepred", default=None,
                     help="BENCH_curve_pred json to gate (omit to skip)")
-    ap.add_argument("--mvm", default=None,
-                    help="BENCH_mvm json to gate (omit to skip)")
     ap.add_argument("--serving", default=None,
                     help="BENCH_serving json to gate (omit to skip)")
     ap.add_argument("--scaling", default=None,
@@ -327,19 +300,18 @@ def main(argv=None) -> int:
     backends = load(args.backends)
     automl = load(args.automl)
     curvepred = load(args.curvepred)
-    mvm = load(args.mvm)
     serving = load(args.serving)
     scaling = load(args.scaling)
     reliability = load(args.reliability)
-    if all(p is None for p in (backends, automl, curvepred, mvm, serving,
-                               scaling, reliability)):
+    if all(p is None for p in (backends, automl, curvepred, serving, scaling,
+                               reliability)):
         print("benchmark gate FAILED: no sections given — pass at least "
-              "one of --backends/--automl/--curvepred/--mvm/--serving/"
+              "one of --backends/--automl/--curvepred/--serving/"
               "--scaling/--reliability")
         return 1
 
     failures = check(baseline, backends, automl, args.factor, curvepred,
-                     mvm, serving, scaling, reliability)
+                     serving, scaling, reliability)
     if failures:
         print("\n".join(["", "benchmark gate FAILED:"] + failures))
         return 1

@@ -15,20 +15,12 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="smaller sizes / fewer seeds")
     ap.add_argument("--only", default="",
-                    help="comma list: scaling,prediction,mvm,automl,roofline")
+                    help="comma list: scaling,prediction,automl,roofline")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")
     t_all = time.time()
-
-    if only is None or "mvm" in only:
-        from . import bench_mvm
-        t0 = time.time()
-        rows = bench_mvm.main(sizes=(32, 64, 128) if args.quick
-                              else (32, 64, 128, 256))
-        print(f"bench_mvm,{(time.time()-t0)*1e6:.0f},"
-              f"structured_mvm_n256_us={rows[-1][1]:.0f}")
 
     if only is None or "scaling" in only:
         from . import bench_scaling

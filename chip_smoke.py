@@ -11,7 +11,7 @@ that does not use the Pallas kernel:
    2021), curves censored at random lengths. ``fit`` with
    ``backend="pallas"`` (a 2-step device L-BFGS polish), then
    ``posterior(state).mean`` and ``.final()``. The kernel MVM on the
-   fitted Grams must match ``kernels.ref.lk_mvm_ref`` and the ``pallas``
+   fitted Grams must match ``core.mvm.lk_mvm`` and the ``pallas``
    posterior mean must match the ``iterative`` engine's (einsum MVM);
 2. exact reference at n = 200: the ``pallas`` posterior mean against the
    dense exact posterior; ``make_mll`` against ``mll_cholesky`` printed;
@@ -128,11 +128,10 @@ def on_device(x, platform: str) -> bool:
 
 def fitted_operator_check(state, engine_name: str, seed: int, platform: str,
                           tol: float = TOL_KERNEL):
-    """The engine's MVM on the state's fitted Grams against lk_mvm_ref."""
+    """The engine's MVM on the state's fitted Grams against lk_mvm (XLA)."""
     import jax
     import jax.numpy as jnp
-    from repro.core import get_engine, gram_matrices
-    from repro.kernels.ref import lk_mvm_ref
+    from repro.core import get_engine, gram_matrices, lk_mvm
 
     cfg = state.config
     data = state.data
@@ -146,9 +145,9 @@ def fitted_operator_check(state, engine_name: str, seed: int, platform: str,
     mvm = jax.jit(lambda v: A(v))
     out = mvm(u)
     with reference_precision():
-        ref = lk_mvm_ref(K1, K2, data.mask, u, noise)
+        ref = lk_mvm(K1, K2, data.mask, u, noise)
     rel = float(jnp.linalg.norm(out - ref) / jnp.linalg.norm(ref))
-    print(f"  kernel MVM {tuple(u.shape)} vs lk_mvm_ref: relative error "
+    print(f"  kernel MVM {tuple(u.shape)} vs lk_mvm: relative error "
           f"{rel:.3e}")
     check(rel <= tol, f"kernel MVM relative error {rel:.3e} <= {tol:g}")
     if platform == "tpu":

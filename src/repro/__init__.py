@@ -2,7 +2,7 @@
 
 Layout:
   repro.core        — the paper's model (LKGP) and its linear algebra
-  repro.kernels     — Pallas TPU kernels (lk_mvm, gram) + jnp oracles
+  repro.kernels     — Pallas TPU kernel: the masked latent-Kronecker MVM
   repro.models      — the 10 assigned LM architectures (pure JAX)
   repro.configs     — published configs + reduced smoke variants
   repro.data        — learning-curve prior + token pipeline

@@ -25,14 +25,14 @@ from .vmem import audit_candidate_space, best_fitting_blocks
 
 
 def _run_vmem_audit(out) -> int:
-    """Audit the autotuner's candidate space against the VMEM budget.
+    """Audit the {64, 128, 256} block candidates against the VMEM budget.
 
-    The raw {64, 128, 256} sweep is *expected* to contain oversized
-    combinations at large (n, m) — the invariant we enforce is that the
-    VMEM-filtered chooser never returns one of them: every shape bucket
-    either has a fitting best pair or is explicitly marked as requiring
-    the two-stage fallback. A violation here means vmem.py and
-    kernels/autotune.py have drifted apart.
+    The raw grid is *expected* to contain oversized combinations at large
+    (n, m) — the invariant we enforce is that the VMEM-filtered chooser
+    never returns one of them: every shape bucket either has a fitting
+    best pair or is marked as taking the XLA fallback of
+    ``lk_mvm_fused``. A violation here means the byte model and the
+    chooser in vmem.py have drifted apart.
     """
     from .vmem import fused_vmem_breakdown
 
@@ -45,14 +45,14 @@ def _run_vmem_audit(out) -> int:
             for prec in ("f32", "bf16"):
                 pair = best_fitting_blocks(n, m, precision=prec)
                 if pair is None:
-                    no_fit += 1   # fine: two-stage fallback handles it
+                    no_fit += 1   # fine: the XLA fallback handles it
                 elif not fused_vmem_breakdown(n, m, *pair, prec).fits():
                     failures += 1
                     print(f"vmem: FILTER BUG — chooser returned oversized "
                           f"{pair} for (n={n}, m={m}, {prec})", file=out)
     print(f"vmem: {len(rows)} oversized (shape, candidate) combinations in "
           f"the raw {{64,128,256}} sweep; {no_fit} (shape, precision) "
-          "bucket(s) require the two-stage fallback; filtered chooser "
+          "bucket(s) take the XLA fallback; filtered chooser "
           f"emitted {'no' if not failures else failures} oversized pair(s).",
           file=out)
     return failures

@@ -9,12 +9,11 @@ accumulator resident in VMEM. The square kernel (``_square_call``, behind
 ``lk_mvm_fused``) works in the transposed, lane-dense layout and keeps a
 chunk of right-hand sides per step (:func:`square_plan`).
 TPU VMEM is ~16 MiB per core; a (block_n, block_m) choice whose resident
-set exceeds it fails at ``pallas_call`` compile time on hardware — long
-after the autotuner committed to it, and invisibly on CPU where the
-kernel runs in interpret mode. This module computes the **exact** bytes
-implied by a block choice (including (sublane, lane) tile rounding and
-the pipeline's double buffering) so oversized configurations are rejected
-*before* ``pallas_call`` ever runs:
+set exceeds it fails at ``pallas_call`` compile time on hardware, and
+invisibly on CPU where the kernel runs in interpret mode. This module
+computes the **exact** bytes implied by a block choice (including
+(sublane, lane) tile rounding and the pipeline's double buffering) so
+oversized configurations are rejected *before* ``pallas_call`` ever runs:
 
 * :func:`effective_blocks` — the block edges the kernels really use: an
   axis that one block covers is taken whole, a tiled axis uses the block
@@ -23,14 +22,16 @@ the pipeline's double buffering) so oversized configurations are rejected
   model, mirroring the kernel's BlockSpecs one-to-one;
 * :func:`check_fused_blocks` — raise :class:`VmemBudgetError` when a
   choice exceeds the budget (called by the fused kernels themselves);
-* :func:`best_fitting_blocks` — the largest-throughput candidate pair
-  that fits (used by the autotuner to filter its sweep);
+* :func:`best_fitting_blocks` — the fitting candidate pair with the
+  fewest grid steps (the distributed engine's per-shard blocks);
+* :func:`kernel_blocks` — the blocks ``lk_mvm_fused`` takes when none
+  are given, or None where no pair fits and it runs the XLA body;
 * :func:`square_plan` / :func:`square_vmem_breakdown` — the square
   kernel's tiles and batch chunk, and their byte model;
 * :func:`audit_candidate_space` — sweep representative shape buckets and
-  report every (shape, candidate) combination the autotuner could emit
-  that does not fit; the *filtered* sweep is provably clean while the raw
-  {64, 128, 256} grid is not (see tests/test_analysis.py).
+  report every (shape, candidate) combination over budget; the
+  *filtered* choosers are provably clean while the raw {64, 128, 256}
+  grid is not (see tests/test_analysis.py).
 
 Pure stdlib — importable (and CI-checkable) without jax.
 """
@@ -41,12 +42,12 @@ from dataclasses import dataclass
 __all__ = ["VMEM_BUDGET_BYTES", "VmemBudgetError", "VmemBreakdown",
            "block_edge", "effective_blocks", "fused_vmem_breakdown",
            "fused_vmem_bytes", "check_fused_blocks", "best_fitting_blocks",
-           "SquarePlan", "SquareVmemBreakdown", "square_vmem_breakdown",
-           "square_plan", "audit_candidate_space"]
+           "kernel_blocks", "SquarePlan", "SquareVmemBreakdown",
+           "square_vmem_breakdown", "square_plan", "audit_candidate_space"]
 
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024   # 16 MiB per TPU core
 
-# Matches repro.kernels.lk_mvm: candidate sweep and minimum block edges.
+# Candidate block edges and the minimum block edge per precision.
 _CANDIDATES = (64, 128, 256)
 _MIN_EDGE = {"f32": 8, "bf16": 16}
 _ITEMSIZE = {"f32": 4, "bf16": 2}
@@ -159,9 +160,9 @@ def check_fused_blocks(n: int, m: int, block_n: int, block_m: int,
             f"at shape (n={n}, m={m}, {precision}) need {bd.total} bytes "
             f"of VMEM (> budget {budget}): the (bn={bn}, mpad={mpad}) row "
             f"and column strips are {bd.u_strip + bd.k2_strip} bytes, "
-            "twice over when double buffered. Use smaller blocks, or the "
-            "two-stage kernel (fused=False) whose intermediate lives in "
-            "HBM.")
+            "twice over when double buffered. Use smaller blocks, or leave "
+            "them to lk_mvm_fused, which runs the XLA body where no pair "
+            "fits.")
     return bd
 
 
@@ -178,11 +179,10 @@ def best_fitting_blocks(n: int, m: int, precision: str = "f32",
                         ) -> tuple[int, int] | None:
     """The fitting candidate pair with the fewest grid steps, or None.
 
-    Fewest grid steps == fewest stage-R recomputes (the analytic optimum
-    the autotuner's heuristic mode targets); ties break toward larger
-    blocks. Returns None when no candidate pair fits — the fused kernel
-    cannot run this shape within budget and callers must fall back to the
-    two-stage kernel.
+    Fewest grid steps == fewest stage-R recomputes; ties break toward
+    larger blocks. Returns None when no candidate pair fits — the fused
+    kernel cannot run this shape within budget (``lk_mvm_fused`` then runs
+    the XLA body).
     """
     best: tuple[int, int] | None = None
     best_key: tuple | None = None
@@ -313,17 +313,36 @@ def square_plan(n: int, m: int, B: int, block_n: int,
     return best
 
 
+def kernel_blocks(n: int, m: int,
+                  precision: str = "f32") -> tuple[int, int] | None:
+    """(block_n, block_m) of ``lk_mvm_fused`` at (n, m) when none are given.
+
+    The smallest candidate covering each axis (the single-sweep regime),
+    kept if the kernel it selects fits the budget: the square kernel,
+    whose smallest batch chunk fits for every B if it fits for one, or
+    else the row-strip kernel. Otherwise the best *fitting* row-strip
+    pair; None when no candidate fits at all, and ``lk_mvm_fused`` runs
+    the XLA body.
+    """
+    bn = next((c for c in _CANDIDATES if c >= n), _CANDIDATES[-1])
+    bm = next((c for c in _CANDIDATES if c >= m), _CANDIDATES[-1])
+    if (square_plan(n, m, 1, bn, precision) is not None
+            or fused_vmem_breakdown(n, m, bn, bm, precision).fits()):
+        return bn, bm
+    return best_fitting_blocks(n, m, precision)
+
+
 def audit_candidate_space(shapes=None,
                           candidates: tuple[int, ...] = _CANDIDATES,
                           budget: int = VMEM_BUDGET_BYTES) -> list[dict]:
     """Every (shape, precision, candidate) combination over budget.
 
-    ``shapes`` defaults to the power-of-two (n, m) buckets the autotuner
-    caches on, up to (8192, 8192) — the paper's target regime. The
-    returned rows are what the raw {64, 128, 256} sweep *could* pick
-    without the VMEM filter; an empty result for the filtered chooser
-    (:func:`best_fitting_blocks` composed over the same shapes) is the
-    invariant the CI gate enforces.
+    ``shapes`` defaults to the power-of-two (n, m) buckets up to
+    (8192, 8192) — the paper's target regime. The returned rows are what
+    the raw {64, 128, 256} grid *could* pick without the VMEM filter; that
+    the filtered chooser (:func:`best_fitting_blocks` over the same
+    shapes) never returns one of them is the invariant the CI gate
+    enforces.
     """
     if shapes is None:
         buckets = [2 ** k for k in range(3, 14)]        # 8 .. 8192

@@ -20,12 +20,11 @@ Lanczos quadrature (:mod:`~repro.core.slq`), the latent-Kronecker MVM
 (:mod:`~repro.core.mvm`), Matheron sampling, transforms, and priors.
 """
 from .caching import LRUCache
-from .engines import (ENGINES, CustomMVMEngine, DenseEngine,
-                      DistributedEngine, InferenceEngine, IterativeEngine,
+from .engines import (ENGINES, DenseEngine, DistributedEngine,
+                      InferenceEngine, IterativeEngine,
                       LatentKroneckerOperator, PallasEngine,
                       StackedSolveResult, engine_cache_stats, get_engine,
-                      list_backends, make_mll, make_mll_iterative,
-                      mll_cholesky, register_engine)
+                      list_backends, make_mll, mll_cholesky, register_engine)
 from .gp_kernels import KERNELS_1D, matern12, matern32, matern52, rbf_ard
 from .lbfgs import LBFGSResult, lbfgs_minimize
 from .lkgp import LKGP
@@ -83,8 +82,8 @@ __all__ = [
     # engines
     "InferenceEngine", "ENGINES", "get_engine", "register_engine",
     "list_backends", "DenseEngine", "IterativeEngine", "PallasEngine",
-    "DistributedEngine", "CustomMVMEngine", "LatentKroneckerOperator",
-    "StackedSolveResult", "make_mll", "make_mll_iterative", "mll_cholesky",
+    "DistributedEngine", "LatentKroneckerOperator",
+    "StackedSolveResult", "make_mll", "mll_cholesky",
     # posterior + facade
     "PosteriorLike", "Posterior", "posterior", "joint_grams", "LKGP",
     "BatchedPosterior", "posterior_batch",

@@ -13,8 +13,9 @@ Four implementations are registered:
 * ``iterative``   — batched CG + stochastic Lanczos quadrature (the paper's
                     method), O(n^2 m + n m^2) per MVM.
 * ``pallas``      — the iterative engine with every MVM routed through the
-                    Pallas TPU kernel (:mod:`repro.kernels.ops`); runs in
-                    interpret mode on the CPU so it is testable there.
+                    Pallas TPU kernel (:func:`repro.kernels.lk_mvm_fused`);
+                    runs in interpret mode on the CPU so it is testable
+                    there.
 * ``distributed`` — the iterative engine over the shard_map row-sharded
                     operator (:mod:`repro.distributed.lkgp_dist`), reachable
                     from the top-level API via ``LKGPConfig(backend=...)``.
@@ -46,8 +47,8 @@ from .state import GPData, LKGPConfig, LKGPParams, gram_matrices
 __all__ = [
     "InferenceEngine", "ENGINES", "register_engine", "get_engine",
     "engine_cache_stats", "list_backends", "DenseEngine", "IterativeEngine", "PallasEngine",
-    "DistributedEngine", "CustomMVMEngine", "LatentKroneckerOperator",
-    "StackedSolveResult", "make_mll", "mll_cholesky", "make_mll_iterative",
+    "DistributedEngine", "LatentKroneckerOperator",
+    "StackedSolveResult", "make_mll", "mll_cholesky",
     "solve_tally", "escalation_tally",
 ]
 
@@ -336,29 +337,17 @@ class IterativeEngine:
         return slq_logdet(A, probes, config.slq_iters, jnp.sum(data.mask))
 
 
-class CustomMVMEngine(IterativeEngine):
-    """Iterative engine over a user-supplied ``mvm(K1, K2, mask, u, noise=...)``."""
-
-    name = "custom"
-
-    def __init__(self, mvm: Callable):
-        self._mvm = mvm
-
-    def operator_from_grams(self, K1, K2, mask, noise):
-        return LatentKroneckerOperator(K1, K2, mask, noise, mvm=self._mvm)
-
-
 # --------------------------------------------------------------------------
 # pallas (iterative, MVMs through the TPU kernel)
 # --------------------------------------------------------------------------
 def _pallas_mvm_raw(K1, K2, mask, u, noise):
-    # Import at call time: repro.kernels imports repro.core.gp_kernels, so a
-    # module-level import here would be circular. force_pallas=True runs the
-    # kernel on every backend: compiled by Mosaic on TPU, in interpret mode
-    # on the CPU (so tests exercise the same code path). f64 operands are
-    # cast to f32 at the kernel boundary and the result cast back.
-    from ..kernels import ops
-    return ops.lk_mvm_op(K1, K2, mask, u, noise, force_pallas=True)
+    # Import at call time: repro.kernels imports repro.core.mvm, so a
+    # module-level import here would be circular. The kernel runs on every
+    # backend: compiled by Mosaic on TPU, in interpret mode on the CPU (so
+    # tests exercise the same code path). f64 operands are cast to f32 at
+    # the kernel boundary and the result cast back.
+    from ..kernels import lk_mvm as kernel
+    return kernel.lk_mvm_fused(K1, K2, mask, u, noise)
 
 
 def _with_analytic_vjp(mvm_raw: Callable) -> Callable:
@@ -594,15 +583,3 @@ def make_mll(config: LKGPConfig, engine: "InferenceEngine") -> Callable:
 
     mll.defvjp(_fwd, _bwd)
     return mll
-
-
-def make_mll_iterative(cfg: LKGPConfig, mvm_impl=None):
-    """Iterative MLL with custom VJP (backward-compatible entry point).
-
-    Returns ``mll(params, X, t, Y, mask, probes)``. With ``mvm_impl`` given
-    (signature ``mvm(K1, K2, mask, u, noise=...)``), every MVM — CG, SLQ,
-    and the quadratic-form gradients — routes through it; this is how
-    ``LKGPConfig.use_pallas`` threads the Pallas kernel into the objective.
-    """
-    engine = IterativeEngine() if mvm_impl is None else CustomMVMEngine(mvm_impl)
-    return make_mll(cfg, engine)

@@ -38,23 +38,21 @@ import warnings
 from typing import Any
 
 # Re-exports: the historical public surface of this module.
-from .engines import (CustomMVMEngine, DenseEngine, DistributedEngine,
-                      InferenceEngine, IterativeEngine, PallasEngine,
-                      get_engine, list_backends, make_mll, make_mll_iterative,
-                      mll_cholesky, register_engine)
+from .engines import (DenseEngine, DistributedEngine, InferenceEngine,
+                      IterativeEngine, PallasEngine, get_engine,
+                      list_backends, make_mll, mll_cholesky, register_engine)
 from .posterior import Posterior, joint_grams, posterior
 from .state import (GPData, LKGPConfig, LKGPParams, LKGPState, extend, fit,
                     fit_batch, gram_matrices, init_params, log_prior, refit,
                     resolve_backend, unstack)
 
 __all__ = ["LKGPConfig", "LKGPParams", "LKGP", "LKGPState", "GPData",
-           "init_params", "gram_matrices", "mll_cholesky",
-           "make_mll_iterative", "make_mll", "log_prior", "fit", "fit_batch",
-           "extend", "refit", "unstack", "resolve_backend", "Posterior",
-           "posterior", "joint_grams", "InferenceEngine", "get_engine",
-           "register_engine", "list_backends", "DenseEngine",
-           "IterativeEngine", "PallasEngine", "DistributedEngine",
-           "CustomMVMEngine"]
+           "init_params", "gram_matrices", "mll_cholesky", "make_mll",
+           "log_prior", "fit", "fit_batch", "extend", "refit", "unstack",
+           "resolve_backend", "Posterior", "posterior", "joint_grams",
+           "InferenceEngine", "get_engine", "register_engine",
+           "list_backends", "DenseEngine", "IterativeEngine", "PallasEngine",
+           "DistributedEngine"]
 
 # Legacy names for the backends as reported by ``mll_method_used``.
 _LEGACY_METHOD = {"dense": "cholesky"}

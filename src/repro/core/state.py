@@ -68,9 +68,9 @@ class LKGPConfig:
     ``backend`` selects the inference engine (one front door for all four
     code paths): ``"dense"`` (exact Cholesky), ``"iterative"`` (CG + SLQ),
     ``"pallas"`` (CG + SLQ with every MVM routed through the Pallas TPU
-    kernel in :mod:`repro.kernels.ops`), ``"distributed"`` (shard_map row
-    sharding over a device mesh). ``"auto"`` resolves from the legacy
-    ``mll_method`` / ``use_pallas`` fields and the observation count.
+    kernel :func:`repro.kernels.lk_mvm_fused`), ``"distributed"``
+    (shard_map row sharding over a device mesh). ``"auto"`` resolves from
+    the legacy ``mll_method`` field and the observation count.
     """
     t_kernel: str = "matern12"
     backend: str = "auto"           # "auto" | dense | iterative | pallas | distributed
@@ -118,7 +118,6 @@ class LKGPConfig:
     # which is what invalidates the cache.
     posterior_cache: bool = True
     seed: int = 0
-    use_pallas: bool = False        # legacy alias for backend="pallas"
     # Reliability policy for eager engine solves (repro.core.solvers.guarded):
     # "strict" raises GuardedSolveError on any degraded solve; "escalate"
     # (default) walks the jitter -> solver-switch -> dense-fallback ladder
@@ -243,8 +242,6 @@ def resolve_backend(config: LKGPConfig, n_obs: int) -> str:
             raise ValueError(f"unknown backend {config.backend!r}; "
                              f"expected one of {BACKENDS}")
         return config.backend
-    if config.use_pallas:
-        return "pallas"
     if config.mll_method == "cholesky":
         return "dense"
     if config.mll_method == "iterative":
@@ -327,7 +324,7 @@ def _objective_cache_key(cfg: LKGPConfig) -> tuple:
     return (cfg.t_kernel, cfg.backend, cfg.mll_method, cfg.auto_cholesky_max,
             cfg.cg_tol, cfg.cg_max_iters, cfg.precond_rank, cfg.solver,
             cfg.sgd_iters, cfg.sgd_momentum, cfg.sgd_lr, cfg.slq_probes,
-            cfg.slq_iters, cfg.slq_via_cg, cfg.jitter, cfg.use_pallas)
+            cfg.slq_iters, cfg.slq_via_cg, cfg.jitter)
 
 
 def _lower_bounds(d: int, batch: int = 1) -> np.ndarray:

@@ -1,11 +1,4 @@
-"""Pallas TPU kernels for the paper's compute hot-spots."""
-from .autotune import CANDIDATE_BLOCKS, autotune_blocks
-from .gram import rbf_gram_pallas
-from .lk_mvm import lk_mvm_fused, lk_mvm_pallas, lk_mvm_two_stage
-from .ops import lk_mvm_op, rbf_gram_op
-from .ref import lk_mvm_ref, rbf_gram_ref
+"""Pallas TPU kernels for the paper's compute hot-spot, the masked MVM."""
+from .lk_mvm import lk_mvm_fused, lk_mvm_fused_rows
 
-__all__ = ["rbf_gram_pallas", "lk_mvm_pallas", "lk_mvm_fused",
-           "lk_mvm_two_stage", "lk_mvm_op", "rbf_gram_op",
-           "lk_mvm_ref", "rbf_gram_ref", "autotune_blocks",
-           "CANDIDATE_BLOCKS"]
+__all__ = ["lk_mvm_fused", "lk_mvm_fused_rows"]

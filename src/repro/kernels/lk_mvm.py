@@ -6,45 +6,43 @@ This is the inner loop of every CG iteration in the paper (Section 2): on
 GPU/GPyTorch it is two cuBLAS calls plus separate elementwise masking
 kernels, i.e. four full HBM round-trips of the (B, n, m) intermediate.
 
-Three implementations live here:
+:func:`lk_mvm_fused` is the one entry point (the ``pallas`` engine's
+MVM). It picks its layout from the shape, with blocks from
+:func:`repro.analysis.vmem.kernel_blocks` unless given:
 
-:func:`lk_mvm_fused` (the default behind :func:`lk_mvm_pallas`)
-    ONE ``pallas_call`` in the transposed, lane-dense layout
-    ``out[b]^T = mask^T * (K2^T um[b]^T K1^T) + noise * um[b]^T`` with
-    ``um = mask * U``. Grid (B-chunks, n-lane tiles, K1 sweep): each step
-    forms the stage-R tile ``T = K2^T um[b]^T`` for a chunk of
-    right-hand sides straight into VMEM, stacked on the MXU's rows, and
-    accumulates ``T @ K1^T[k, j]`` into the resident output block, so K1
-    is read once per chunk and the (B, n, m) f32 intermediate NEVER
-    touches HBM. m is held whole, padded to the sublane multiple; the
-    lane tile and the chunk follow from the observed B and the VMEM model
-    (:func:`repro.analysis.vmem.square_plan`). The transposes fuse into
-    the wrapper's padding copies. Supports a bf16-inputs / f32-accumulate
-    mode (``precision="bf16"``); block_n (the sweep depth) comes from
-    :mod:`repro.kernels.autotune` when not given explicitly. Where m is
-    too wide to hold K2 whole (thousands of epochs) it runs the row-strip
-    kernel below instead.
+* the **square kernel**, where K2 and one right-hand side's tiles fit
+  VMEM (:func:`repro.analysis.vmem.square_plan`): ONE ``pallas_call`` in
+  the transposed, lane-dense layout
+  ``out[b]^T = mask^T * (K2^T um[b]^T K1^T) + noise * um[b]^T`` with
+  ``um = mask * U``. Grid (B-chunks, n-lane tiles, K1 sweep): each step
+  forms the stage-R tile ``T = K2^T um[b]^T`` for a chunk of right-hand
+  sides straight into VMEM, stacked on the MXU's rows, and accumulates
+  ``T @ K1^T[k, j]`` into the resident output block, so K1 is read once
+  per chunk and the (B, n, m) f32 intermediate NEVER touches HBM. m is
+  held whole, padded to the sublane multiple; block_n is the depth of the
+  K1 sweep, and the lane tile and the chunk follow from the observed B
+  and the VMEM model. The transposes fuse into the wrapper's padding
+  copies;
+* the **row-strip kernel** below, where m is too wide to hold K2 whole
+  (thousands of epochs) but a (block_n, block_m) pair fits;
+* the XLA body :func:`repro.core.mvm.lk_mvm` (einsums at ``HIGHEST``),
+  where no block pair fits (m in the thousands with n in the hundreds).
 
-:func:`lk_mvm_fused_rows` (the distributed engine's per-shard body)
-    ONE ``pallas_call`` over one row shard, in the (n, m) layout. Grid
-    (B, n-rows, m-cols) with an inner K1-row sweep; each step recomputes
-    the per-block-row tile ``T = (mask * U)[k, :] @ K2[:, j]`` straight
-    into VMEM and accumulates ``K1[i, k] @ T``; the noise/mask epilogue
-    reads its own (i, j) tiles of mask and U. VMEM per step is
-    O(block_n * m + m * block_m), so it reaches m <~ 4096.
+Both kernels support a bf16-inputs / f32-accumulate mode
+(``precision="bf16"``).
 
-:func:`lk_mvm_two_stage` (the committed baseline the benchmarks gate
-    against) — two ``pallas_call``s with the masked intermediate
-    materialised in HBM between them:
-
-    Stage R (right):  T   = (mask * U) @ K2        grid (B, n/bn, m/bj, m/bk)
-    Stage L (left):   out = mask * (K1 @ T) + noise * (mask * U)
-                                                   grid (B, n/bi, m/bj, n/bk)
+:func:`lk_mvm_fused_rows` (the distributed engine's per-shard body) runs
+the row-strip kernel over one row shard, in the (n, m) layout. Grid
+(B, n-rows, m-cols) with an inner K1-row sweep; each step recomputes the
+per-block-row tile ``T = (mask * U)[k, :] @ K2[:, j]`` straight into VMEM
+and accumulates ``K1[i, k] @ T``; the noise/mask epilogue reads its own
+(i, j) tiles of mask and U. VMEM per step is O(block_n * m + m * block_m),
+so it reaches m <~ 4096.
 
 Accumulation runs over the innermost grid axis: into an f32 VMEM scratch
-whose epilogue applies the mask and noise term on the final step (row-strip
-and two-stage kernels), or into the resident output block, masked as it
-goes (square kernel).
+whose epilogue applies the mask and noise term on the final step
+(row-strip kernel), or into the resident output block, masked as it goes
+(square kernel).
 
 Mosaic constraints the wrappers honour: every operand reaching a
 ``pallas_call`` is f32 or bf16 (f64 callers are cast at the boundary and
@@ -63,11 +61,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..analysis.vmem import (block_edge, check_fused_blocks, effective_blocks,
-                             square_plan)
+from ..analysis.vmem import (check_fused_blocks, effective_blocks,
+                             kernel_blocks, square_plan)
+from ..core.mvm import lk_mvm
 
-__all__ = ["lk_mvm_pallas", "lk_mvm_fused", "lk_mvm_fused_rows",
-           "lk_mvm_two_stage"]
+__all__ = ["lk_mvm_fused", "lk_mvm_fused_rows"]
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
@@ -104,111 +102,11 @@ def _dot(a, b):
                        preferred_element_type=jnp.float32)
 
 
-def _stage_right_kernel(u_ref, mask_ref, k2_ref, o_ref, acc_ref, *, nk: int):
-    """T[b, i, j] += (mask*U)[b, i, k] @ K2[k, j]."""
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += _dot(u_ref[0] * mask_ref[...], k2_ref[...])
-
-    @pl.when(k == nk - 1)
-    def _done():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _stage_left_kernel(k1_ref, t_ref, mask_ref, u_ref, noise_ref, o_ref,
-                       acc_ref, *, nk: int):
-    """out[b, i, j] = mask * (K1[i, k] @ T[b, k, j]) + noise * mask * U."""
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += _dot(k1_ref[...], t_ref[0])
-
-    @pl.when(k == nk - 1)
-    def _done():
-        mask = mask_ref[...]
-        noise = noise_ref[0, 0]
-        out = mask * acc_ref[...] + noise * (mask * u_ref[0])
-        o_ref[0] = out.astype(o_ref.dtype)
-
-
 def _pad_to(x, mults):
     pads = [(0, (-s) % mult) for s, mult in zip(x.shape, mults)]
     if all(p == (0, 0) for p in pads):
         return x
     return jnp.pad(x, pads)
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "block_m", "interpret"))
-def lk_mvm_two_stage(K1: jnp.ndarray, K2: jnp.ndarray, mask: jnp.ndarray,
-                     u: jnp.ndarray, noise=0.0, *, block_n: int = 128,
-                     block_m: int = 128,
-                     interpret: bool | None = None) -> jnp.ndarray:
-    """Two-stage masked Kronecker MVM (HBM-materialised intermediate).
-
-    Kept as the benchmark baseline for the fused kernel; u: (..., n, m) ->
-    same shape. Zero-padding to block multiples is harmless: padded
-    rows/cols of mask are zero, K2/K1 padding contributes zero partial
-    products.
-    """
-    interpret = resolve_interpret(interpret)
-    n, m = mask.shape
-    batch_shape = u.shape[:-2]
-    dtype = u.dtype
-    f32 = lambda x: jnp.asarray(x).astype(jnp.float32)  # noqa: E731
-    u3 = f32(u).reshape((-1, n, m))
-    B = u3.shape[0]
-
-    bn = block_edge(block_n, n)
-    bm = block_edge(block_m, m)
-    K1p = _pad_to(f32(K1), (bn, bn))
-    K2p = _pad_to(f32(K2), (bm, bm))
-    maskp = _pad_to(f32(mask), (bn, bm))
-    up = _pad_to(u3, (1, bn, bm))
-    npad, mpad = maskp.shape
-    noise_arr = jnp.asarray(noise, jnp.float32).reshape(1, 1)
-
-    gn, gm, gkm, gkn = npad // bn, mpad // bm, mpad // bm, npad // bn
-
-    # Stage R: T = (mask * U) @ K2
-    t = pl.pallas_call(
-        functools.partial(_stage_right_kernel, nk=gkm),
-        grid=(B, gn, gm, gkm),
-        in_specs=[
-            pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, i, k)),   # U
-            pl.BlockSpec((bn, bm), lambda b, i, j, k: (i, k)),         # mask
-            pl.BlockSpec((bm, bm), lambda b, i, j, k: (k, j)),         # K2
-        ],
-        out_specs=pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, npad, mpad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bn, bm), jnp.float32)],
-        interpret=interpret,
-    )(up, maskp, K2p)
-
-    # Stage L: out = mask * (K1 @ T) + noise * mask * U
-    out = pl.pallas_call(
-        functools.partial(_stage_left_kernel, nk=gkn),
-        grid=(B, gn, gm, gkn),
-        in_specs=[
-            pl.BlockSpec((bn, bn), lambda b, i, j, k: (i, k)),         # K1
-            pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, k, j)),   # T
-            pl.BlockSpec((bn, bm), lambda b, i, j, k: (i, j)),         # mask
-            pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, i, j)),   # U
-            smem_scalar_spec(),                                        # noise
-        ],
-        out_specs=pl.BlockSpec((1, bn, bm), lambda b, i, j, k: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, npad, mpad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bn, bm), jnp.float32)],
-        interpret=interpret,
-    )(K1p, t, maskp, up, noise_arr)
-
-    return out[:, :n, :m].reshape(*batch_shape, n, m).astype(dtype)
 
 
 def _fused_kernel(k1_ref, um_ref, k2_ref, mask_ref, u_ref, noise_ref, o_ref,
@@ -218,8 +116,9 @@ def _fused_kernel(k1_ref, um_ref, k2_ref, mask_ref, u_ref, noise_ref, o_ref,
 
     ``um = mask * u`` over all rows of the K1 sweep (k indexes GLOBAL
     block rows); the epilogue's mask/u tiles are dedicated inputs at the
-    output block (i, j), so the same kernel serves the square MVM and one
-    row shard of it (i indexes the shard's local rows).
+    output block (i, j), so the same kernel serves the whole MVM (where m
+    is too wide for the square layout) and one row shard of it (i indexes
+    the shard's local rows).
     """
     k = pl.program_id(3)
 
@@ -399,24 +298,33 @@ def _square_call(K1, K2, mask, u3, noise, *, plan, precision: str,
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m",
                                              "precision", "interpret"))
 def lk_mvm_fused(K1: jnp.ndarray, K2: jnp.ndarray, mask: jnp.ndarray,
-                 u: jnp.ndarray, noise=0.0, *, block_n: int = 128,
-                 block_m: int = 128, precision: str = "f32",
+                 u: jnp.ndarray, noise=0.0, *, block_n: int | None = None,
+                 block_m: int | None = None, precision: str = "f32",
                  interpret: bool | None = None) -> jnp.ndarray:
     """Single-pass masked Kronecker MVM. u: (..., n, m) -> same shape.
 
-    One ``pallas_call``; the stage-R tile stays in VMEM (see module
-    docstring). ``precision="bf16"`` casts the matmul inputs to bfloat16
-    and accumulates in f32 (the mask/noise epilogue stays f32); the output
+    The layout follows from the shape (see module docstring): the square
+    kernel if its plan fits, else the row-strip kernel, else the XLA
+    body. ``block_n`` / ``block_m`` default to
+    :func:`repro.analysis.vmem.kernel_blocks` (n, m); given explicitly,
+    they are kept, and a row-strip pair over the VMEM budget raises
+    :class:`repro.analysis.vmem.VmemBudgetError`. ``block_n`` is the
+    depth of the K1 sweep; the square layout holds m whole, so
+    ``block_m`` only reaches the row-strip kernel.
+
+    ``precision="bf16"`` casts the matmul inputs to bfloat16 and
+    accumulates in f32 (the mask/noise epilogue stays f32); the output
     keeps u's dtype. Zero-padding to block multiples is harmless: padded
     rows/cols of mask are zero, K2/K1 padding contributes zero partial
     products.
-
-    ``block_n`` is the depth of the K1 sweep. The square layout holds m
-    whole and derives its lane tile and batch chunk from the observed B
-    (:func:`repro.analysis.vmem.square_plan`), so ``block_m`` only matters
-    where m is too wide for it and the row-strip kernel runs instead.
     """
     n, m = mask.shape
+    if block_n is None or block_m is None:
+        blocks = kernel_blocks(n, m, precision)
+        if blocks is None:
+            return lk_mvm(K1, K2, mask, u, noise).astype(u.dtype)
+        block_n = blocks[0] if block_n is None else block_n
+        block_m = blocks[1] if block_m is None else block_m
     u3 = u.reshape((-1, n, m))
     plan = square_plan(n, m, u3.shape[0], block_n, precision)
     if plan is not None:
@@ -456,20 +364,3 @@ def lk_mvm_fused_rows(K1_rows: jnp.ndarray, K2: jnp.ndarray,
                       noise, block_n=block_n, block_m=block_m,
                       precision=precision, interpret=interpret)
     return out[0].astype(u_rows.dtype)
-
-
-def lk_mvm_pallas(K1, K2, mask, u, noise=0.0, *, block_n: int = 128,
-                  block_m: int = 128, interpret: bool | None = None,
-                  fused: bool = True,
-                  precision: str = "f32") -> jnp.ndarray:
-    """Masked Kronecker MVM (back-compatible entry point).
-
-    Dispatches to the single-pass :func:`lk_mvm_fused` kernel by default;
-    ``fused=False`` runs the committed two-stage baseline.
-    """
-    if fused:
-        return lk_mvm_fused(K1, K2, mask, u, noise, block_n=block_n,
-                            block_m=block_m, precision=precision,
-                            interpret=interpret)
-    return lk_mvm_two_stage(K1, K2, mask, u, noise, block_n=block_n,
-                            block_m=block_m, interpret=interpret)

@@ -204,18 +204,30 @@ def test_autotuner_candidates_all_fit_or_none():
                 assert fused_vmem_breakdown(n, m, *pair).fits(), (n, m, pair)
 
 
-def test_autotune_blocks_vmem_filtered():
-    from repro.kernels.autotune import autotune_blocks, clear_cache
+def _traced_kernel_calls(n, m):
+    """(pallas_call count, output shape) of lk_mvm_fused at (n, m) with no
+    blocks given, under eval_shape and make_jaxpr: nothing runs."""
+    import jax.numpy as jnp
 
-    clear_cache()
-    try:
-        blocks = autotune_blocks(512, 8192, timed=False)
-        assert blocks is None      # nothing fits: two-stage fallback
-        blocks = autotune_blocks(512, 512, timed=False)
-        assert blocks is not None
-        assert fused_vmem_breakdown(512, 512, *blocks).fits()
-    finally:
-        clear_cache()
+    from repro.kernels.lk_mvm import lk_mvm_fused
+
+    def fn(K1, K2, mask, u):
+        return lk_mvm_fused(K1, K2, mask, u, 0.1)
+
+    specs = [jax.ShapeDtypeStruct(s, jnp.float32)
+             for s in ((n, n), (m, m), (n, m), (n, m))]
+    text = str(jax.make_jaxpr(fn)(*specs))
+    return text.count("pallas_call["), jax.eval_shape(fn, *specs).shape
+
+
+def test_kernel_blocks_vmem_filtered():
+    from repro.analysis.vmem import kernel_blocks
+
+    assert kernel_blocks(512, 8192) is None    # nothing fits: XLA body
+    assert _traced_kernel_calls(512, 8192) == (0, (512, 8192))
+    blocks = kernel_blocks(512, 512)
+    assert blocks is not None
+    assert fused_vmem_breakdown(512, 512, *blocks).fits()
 
 
 def test_square_vmem_breakdown_matches_blockspecs_at_lcbench():
@@ -223,9 +235,9 @@ def test_square_vmem_breakdown_matches_blockspecs_at_lcbench():
     BlockSpecs byte for byte: f32 blocks, m padded to 56 sublanes, lanes
     to 128; the heuristic's chunk fits 16 MiB and meets the kernel's
     shape targets."""
-    from repro.kernels.autotune import _heuristic
+    from repro.analysis.vmem import kernel_blocks
 
-    block_n, _ = _heuristic(2000, 52)
+    block_n, _ = kernel_blocks(2000, 52)
     plan = square_plan(2000, 52, 65, block_n)
     assert (plan.bk, plan.tn, plan.m8, plan.n_pad) == (256, 2048, 56, 2048)
     c = plan.chunk
@@ -264,26 +276,13 @@ def test_square_plan_fits_budget_and_falls_back(precision):
     assert square_plan(512, 8192, 1, 256, precision) is None
 
 
-def test_lk_mvm_op_falls_back_to_two_stage():
-    """lk_mvm_op on an unfittable shape must route to the two-stage
-    kernel rather than raise (checked via trace only — no execution)."""
-    import jax.numpy as jnp
-
-    from repro.kernels.autotune import clear_cache
-    from repro.kernels.ops import lk_mvm_op
-
-    clear_cache()
-    try:
-        out = jax.eval_shape(
-            lambda: lk_mvm_op(
-                jnp.zeros((64, 64), jnp.float32),
-                jnp.zeros((8192, 8192), jnp.float32),
-                jnp.zeros((64, 8192), jnp.float32),
-                jnp.zeros((64, 8192), jnp.float32),
-                0.1, force_pallas=True))
-        assert out.shape == (64, 8192)
-    finally:
-        clear_cache()
+def test_lk_mvm_fused_falls_back_to_xla():
+    """Where no block pair fits VMEM, lk_mvm_fused runs the XLA body
+    instead of raising: no pallas_call in its trace (eval_shape and
+    make_jaxpr only, no execution). At (64, 8192) a row-strip pair fits
+    and the kernel runs."""
+    assert _traced_kernel_calls(512, 8192) == (0, (512, 8192))
+    assert _traced_kernel_calls(64, 8192) == (1, (64, 8192))
 
 
 # --------------------------------------------------------------------------

@@ -9,9 +9,9 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import (LKGPConfig, cg_solve, fit, gram_matrices,
+from repro.core import (LKGPConfig, cg_solve, fit, get_engine, gram_matrices,
                         init_params, joint_cov_packed, joint_grams,
-                        kron_dense, lk_mvm, lk_operator, make_mll_iterative,
+                        kron_dense, lk_mvm, lk_operator, make_mll,
                         mll_cholesky, posterior, rademacher_probes,
                         slq_logdet)
 from repro.core import gp_kernels as gk
@@ -123,7 +123,7 @@ def test_iterative_mll_matches_cholesky_value_and_grad():
     cfg = LKGPConfig(cg_tol=1e-8, cg_max_iters=2000, slq_probes=256, slq_iters=30)
     probes = rademacher_probes(jax.random.PRNGKey(8), cfg.slq_probes, mask,
                                jnp.float64)
-    mll_it = make_mll_iterative(cfg)
+    mll_it = make_mll(cfg, get_engine("iterative"))
     v_it, g_it = jax.value_and_grad(
         lambda p: mll_it(p, X, t, Y, mask, probes))(params)
     v_ch, g_ch = jax.value_and_grad(

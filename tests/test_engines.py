@@ -44,7 +44,6 @@ def test_resolve_backend_legacy_fields():
     assert resolve_backend(LKGPConfig(), 10_000) == "iterative"
     assert resolve_backend(LKGPConfig(mll_method="cholesky"), 10_000) == "dense"
     assert resolve_backend(LKGPConfig(mll_method="iterative"), 10) == "iterative"
-    assert resolve_backend(LKGPConfig(use_pallas=True), 10) == "pallas"
     assert resolve_backend(LKGPConfig(backend="distributed"), 10) == "distributed"
 
 
@@ -163,19 +162,28 @@ def test_dense_vs_iterative_agree_on_quickstart_task():
 
 
 # --------------------------------------------------------------------------
-# use_pallas flag regression: the flag must change the executed path
+# backend="pallas" regression: the backend must change the executed path
 # --------------------------------------------------------------------------
-def test_use_pallas_flag_changes_executed_path(monkeypatch):
-    from repro.kernels import ops as kernel_ops
+def _spy_kernel(monkeypatch, impl=None):
+    """Count the pallas engine's calls of ``lk_mvm_fused`` (trace time),
+    delegating to ``impl`` or the kernel. JAX's trace caches are cleared
+    first, so a program traced before the spy was in place retraces."""
+    from repro.kernels import lk_mvm as kernel
 
     calls = {"n": 0}
-    real = kernel_ops.lk_mvm_op
+    impl = impl or kernel.lk_mvm_fused
 
     def counting(*args, **kwargs):
         calls["n"] += 1
-        return real(*args, **kwargs)
+        return impl(*args, **kwargs)
 
-    monkeypatch.setattr(kernel_ops, "lk_mvm_op", counting)
+    jax.clear_caches()
+    monkeypatch.setattr(kernel, "lk_mvm_fused", counting)
+    return calls
+
+
+def test_pallas_backend_changes_executed_path(monkeypatch):
+    calls = _spy_kernel(monkeypatch)
     task = _small_task(n=4, m=4)
     base = dict(lbfgs_iters=1, cg_tol=1e-4, cg_max_iters=200, slq_probes=4,
                 slq_iters=8)
@@ -185,8 +193,8 @@ def test_use_pallas_flag_changes_executed_path(monkeypatch):
     assert calls["n"] == 0, "plain iterative backend must not touch Pallas"
 
     fit(task.X, task.t, task.Y, task.mask,
-        LKGPConfig(use_pallas=True, **base))
-    assert calls["n"] > 0, "use_pallas=True must route MVMs through kernels.ops"
+        LKGPConfig(backend="pallas", **base))
+    assert calls["n"] > 0, "backend='pallas' must run MVMs in lk_mvm_fused"
 
 
 def test_pallas_engine_casts_f64_at_the_kernel_boundary():
@@ -256,9 +264,13 @@ def test_exact_engine_methods_are_honoured_by_make_mll():
     np.testing.assert_allclose(v, v_ref, rtol=1e-10)
 
 
-def test_make_mll_iterative_threads_mvm_impl():
-    """Back-compat entry point: a custom mvm_impl is used for every MVM."""
-    task = _small_task(n=4, m=4)
+def test_pallas_mll_routes_every_mvm_through_kernel(monkeypatch):
+    """The pallas engine's MLL runs its MVMs in lk_mvm_fused, forward and
+    backward. The spy computes the XLA body in place of the kernel, so the
+    value and gradient must equal the iterative engine's."""
+    from repro.core import lk_mvm
+
+    task = _small_task(n=4, m=5)
     X = jnp.asarray(task.X)
     t = jnp.asarray(task.t, X.dtype)
     Y = jnp.asarray(task.Y, X.dtype)
@@ -267,20 +279,25 @@ def test_make_mll_iterative_threads_mvm_impl():
     probes = rademacher_probes(jax.random.PRNGKey(1), 8, mask, X.dtype)
     cfg = LKGPConfig(cg_tol=1e-6, cg_max_iters=500, slq_iters=10)
 
-    calls = {"n": 0}
+    calls = _spy_kernel(
+        monkeypatch,
+        lambda K1, K2, mask, u, noise: lk_mvm(K1, K2, mask, u, noise))
+    mll_kernel = make_mll(cfg, get_engine("pallas"))
+    mll_ref = make_mll(cfg, get_engine("iterative"))
+    args = (X, t, Y, mask, probes)
 
-    def spy_mvm(K1, K2, mask, u, noise=0.0):
-        calls["n"] += 1
-        from repro.core import lk_mvm
-        return lk_mvm(K1, K2, mask, u, noise)
+    v1, vjp = jax.vjp(lambda p: mll_kernel(p, *args), params)
+    forward = calls["n"]
+    assert forward > 0, "forward MVMs must run in the kernel"
+    (g1,) = vjp(jnp.ones_like(v1))
+    assert calls["n"] > forward, "backward MVMs must run in the kernel"
 
-    from repro.core import make_mll_iterative
-    mll_spy = make_mll_iterative(cfg, mvm_impl=spy_mvm)
-    mll_ref = make_mll_iterative(cfg)
-    v1 = float(mll_spy(params, X, t, Y, mask, probes))
-    assert calls["n"] > 0
-    v2 = float(mll_ref(params, X, t, Y, mask, probes))
-    np.testing.assert_allclose(v1, v2, rtol=1e-8)
+    v2, g2 = jax.value_and_grad(lambda p: mll_ref(p, *args))(params)
+    np.testing.assert_allclose(float(v1), float(v2), rtol=1e-8)
+    for a, b in zip(jax.tree_util.tree_leaves(g1),
+                    jax.tree_util.tree_leaves(g2)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-7,
+                                   atol=1e-10)
 
 
 def test_mll_bwd_cotangent_dtypes_match_primals():
@@ -294,8 +311,7 @@ def test_mll_bwd_cotangent_dtypes_match_primals():
     probes = rademacher_probes(jax.random.PRNGKey(1), 4, mask, X.dtype)
     cfg = LKGPConfig(cg_tol=1e-4, cg_max_iters=200, slq_iters=8)
 
-    from repro.core import make_mll_iterative
-    mll = make_mll_iterative(cfg)
+    mll = make_mll(cfg, get_engine("iterative"))
     grads = jax.grad(mll, argnums=(1, 2, 3, 4, 5))(
         params, X, t, Y, mask, probes)
     for g, primal in zip(grads, (X, t, Y, mask, probes)):

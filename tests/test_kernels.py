@@ -6,12 +6,10 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.kernels import (CANDIDATE_BLOCKS, autotune_blocks, lk_mvm_fused,
-                           lk_mvm_pallas, lk_mvm_ref, lk_mvm_two_stage,
-                           rbf_gram_pallas, rbf_gram_ref)
-from repro.analysis.vmem import SquarePlan, square_plan
-from repro.kernels import autotune as kernel_autotune
-from repro.kernels import lk_mvm
+from repro.analysis.vmem import SquarePlan, kernel_blocks, square_plan
+from repro.core.gp_kernels import rbf_ard
+from repro.core.mvm import lk_mvm as lk_mvm_xla
+from repro.kernels import lk_mvm, lk_mvm_fused
 
 SHAPES_MVM = [
     # (B, n, m)
@@ -40,49 +38,57 @@ def _mvm_problem(B, n, m, dtype, seed=0):
 @pytest.mark.parametrize("shape", SHAPES_MVM)
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("block", [(16, 16), (128, 128)])
-def test_lk_mvm_pallas_matches_ref(shape, dtype, block):
+def test_pallas_lk_mvm_matches_ref(shape, dtype, block):
     B, n, m = shape
     K1, K2, mask, u = _mvm_problem(B, n, m, dtype)
     noise = 0.37
-    out = lk_mvm_pallas(K1, K2, mask, u, noise, block_n=block[0],
-                        block_m=block[1], interpret=True)
-    ref = lk_mvm_ref(K1, K2, mask, u, noise)
+    out = lk_mvm_fused(K1, K2, mask, u, noise, block_n=block[0],
+                       block_m=block[1], interpret=True)
+    ref = lk_mvm_xla(K1, K2, mask, u, noise)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     assert out.dtype == ref.dtype
 
 
-def test_lk_mvm_pallas_leading_batch_dims():
+def test_pallas_lk_mvm_leading_batch_dims():
+    """Leading batch dims at the blocks lk_mvm_fused derives itself."""
     K1, K2, mask, u = _mvm_problem(6, 16, 12, jnp.float32)
     u4 = u.reshape(2, 3, 16, 12)
-    out = lk_mvm_pallas(K1, K2, mask, u4, 0.1, block_n=16, block_m=16,
-                        interpret=True)
-    ref = lk_mvm_ref(K1, K2, mask, u4, 0.1)
+    out = lk_mvm_fused(K1, K2, mask, u4, 0.1, interpret=True)
+    ref = lk_mvm_xla(K1, K2, mask, u4, 0.1)
     assert out.shape == (2, 3, 16, 12)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)
 
 
+def _rbf_ard_f64(x1, x2, ls, outputscale):
+    """Direct differences in float64 NumPy: the Gram the fit is defined by."""
+    x1, x2, ls = (np.asarray(a, np.float64) for a in (x1, x2, ls))
+    diff = (x1[:, None, :] - x2[None, :, :]) / ls
+    return outputscale * np.exp(-0.5 * np.sum(diff * diff, axis=-1))
+
+
 @pytest.mark.parametrize("n,p,d", [(8, 8, 3), (32, 16, 7), (130, 70, 10),
                                    (64, 64, 1), (16, 16, 260)])
-def test_rbf_gram_pallas_matches_ref(n, p, d):
+def test_rbf_ard_matches_direct_differences(n, p, d):
+    """The Gram the fit and the posterior build (direct differences in
+    f32) against a float64 reference, at wide and narrow d."""
     key = jax.random.PRNGKey(1)
     k1, k2, k3 = jax.random.split(key, 3)
     x1 = jax.random.uniform(k1, (n, d), jnp.float32)
     x2 = jax.random.uniform(k2, (p, d), jnp.float32)
     ls = jnp.exp(jax.random.normal(k3, (d,), jnp.float32) * 0.3)
-    out = rbf_gram_pallas(x1, x2, ls, 1.7, block_n=32, block_d=64,
-                          interpret=True)
-    ref = rbf_gram_ref(x1, x2, ls, 1.7)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=3e-5,
-                               atol=3e-5)
+    out = rbf_ard(x1, x2, ls, 1.7)
+    assert out.shape == (n, p) and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), _rbf_ard_f64(x1, x2, ls, 1.7),
+                               rtol=3e-5, atol=3e-5)
 
 
-def test_rbf_gram_symmetric_unit_diag():
+def test_rbf_ard_symmetric_unit_diag():
     key = jax.random.PRNGKey(2)
     x = jax.random.uniform(key, (40, 5), jnp.float32)
     ls = jnp.ones((5,), jnp.float32)
-    K = np.asarray(rbf_gram_pallas(x, x, ls, 1.0, block_n=16, interpret=True))
+    K = np.asarray(rbf_ard(x, x, ls, 1.0))
     np.testing.assert_allclose(K, K.T, atol=1e-6)
     np.testing.assert_allclose(np.diag(K), 1.0, atol=1e-6)
     assert K.min() >= 0.0 and K.max() <= 1.0 + 1e-6
@@ -93,15 +99,15 @@ def test_rbf_gram_symmetric_unit_diag():
        seed=st.integers(0, 1000))
 def test_property_lk_mvm_random_shapes(n, m, B, seed):
     K1, K2, mask, u = _mvm_problem(B, n, m, jnp.float32, seed)
-    out = lk_mvm_pallas(K1, K2, mask, u, 0.05, block_n=16, block_m=16,
-                        interpret=True)
-    ref = lk_mvm_ref(K1, K2, mask, u, 0.05)
+    out = lk_mvm_fused(K1, K2, mask, u, 0.05, block_n=16, block_m=16,
+                       interpret=True)
+    ref = lk_mvm_xla(K1, K2, mask, u, 0.05)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=3e-5,
                                atol=3e-5)
 
 
 # --------------------------------------------------------------------------
-# fused single-pass kernel: parity with the oracle and the two-stage kernel
+# fused single-pass kernel: parity with the oracle at awkward shapes
 # --------------------------------------------------------------------------
 FUSED_AWKWARD_SHAPES = [
     # (B, n, m): non-multiples of the block, n < 8, B > 1
@@ -124,7 +130,7 @@ def test_lk_mvm_fused_matches_ref_awkward_shapes(shape, block):
     noise = 0.23
     out = lk_mvm_fused(K1, K2, mask, u, noise, block_n=block[0],
                        block_m=block[1], interpret=True)
-    ref = lk_mvm_ref(K1, K2, mask, u, noise)
+    ref = lk_mvm_xla(K1, K2, mask, u, noise)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     assert out.dtype == ref.dtype
@@ -138,7 +144,7 @@ def test_lk_mvm_fused_bf16_mode(shape):
     K1, K2, mask, u = _mvm_problem(B, n, m, jnp.float32)
     out = lk_mvm_fused(K1, K2, mask, u, 0.31, block_n=32, block_m=32,
                        precision="bf16", interpret=True)
-    ref = np.asarray(lk_mvm_ref(K1, K2, mask, u, 0.31))
+    ref = np.asarray(lk_mvm_xla(K1, K2, mask, u, 0.31))
     assert out.dtype == jnp.float32
     scale = np.max(np.abs(ref))
     np.testing.assert_allclose(np.asarray(out), ref, atol=0.05 * scale)
@@ -146,27 +152,12 @@ def test_lk_mvm_fused_bf16_mode(shape):
     np.testing.assert_array_equal(np.asarray(out) * (1 - np.asarray(mask)), 0)
 
 
-def test_lk_mvm_fused_matches_two_stage():
-    """The committed two-stage kernel and the fused kernel are the same
-    operator; lk_mvm_pallas dispatches between them."""
-    K1, K2, mask, u = _mvm_problem(3, 48, 20, jnp.float32)
-    a = lk_mvm_fused(K1, K2, mask, u, 0.5, block_n=32, block_m=32,
-                     interpret=True)
-    b = lk_mvm_two_stage(K1, K2, mask, u, 0.5, block_n=32, block_m=32,
-                         interpret=True)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
-                               atol=2e-5)
-    via_entry = lk_mvm_pallas(K1, K2, mask, u, 0.5, block_n=32, block_m=32,
-                              interpret=True, fused=False)
-    np.testing.assert_array_equal(np.asarray(via_entry), np.asarray(b))
-
-
 def test_lk_mvm_fused_leading_batch_dims():
     K1, K2, mask, u = _mvm_problem(6, 16, 12, jnp.float32)
     u4 = u.reshape(2, 3, 16, 12)
     out = lk_mvm_fused(K1, K2, mask, u4, 0.1, block_n=16, block_m=16,
                        interpret=True)
-    ref = lk_mvm_ref(K1, K2, mask, u4, 0.1)
+    ref = lk_mvm_xla(K1, K2, mask, u4, 0.1)
     assert out.shape == (2, 3, 16, 12)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)
@@ -211,7 +202,7 @@ def test_square_fused_kernel_matches_ref(B, n, m, tiles, precision):
     out = np.asarray(lk_mvm._square_call(K1, K2, mask, u, 0.29, plan=plan,
                                          precision=precision,
                                          interpret=True))
-    ref = np.asarray(lk_mvm_ref(K1, K2, mask, u, 0.29))
+    ref = np.asarray(lk_mvm_xla(K1, K2, mask, u, 0.29))
     assert out.shape == (B, n, m) and out.dtype == np.float32
     if precision == "f32":
         np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
@@ -229,45 +220,87 @@ def test_lk_mvm_fused_square_path_matches_ref(B):
     K1, K2, mask, u = _gappy_problem(B, n, m, seed=B)
     out = lk_mvm_fused(K1, K2, mask, u, 0.41, block_n=128, block_m=64,
                        interpret=True)
-    ref = lk_mvm_ref(K1, K2, mask, u, 0.41)
+    ref = lk_mvm_xla(K1, K2, mask, u, 0.41)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
                                atol=1e-5)
 
 
-def test_autotune_blocks_heuristic_and_cache():
-    """Off-TPU the autotuner picks the single-sweep heuristic (smallest
-    candidate covering each axis), caches per shape bucket, and accepts
-    pre-seeded (e.g. timed) entries."""
-    kernel_autotune.clear_cache()
-    try:
-        bn, bm = autotune_blocks(100, 40, 4, timed=False)
-        assert bn == 128 and bm == 64          # smallest covering candidates
-        assert autotune_blocks(120, 33, 3, timed=False) == (bn, bm)  # bucket hit
-        assert len(kernel_autotune.cache_contents()) == 1
-        big = autotune_blocks(1000, 500, 1, timed=False)
-        assert big == (CANDIDATE_BLOCKS[-1], CANDIDATE_BLOCKS[-1])
-    finally:
-        kernel_autotune.clear_cache()
+def test_kernel_blocks_heuristic():
+    """With no blocks given, lk_mvm_fused takes the single-sweep heuristic:
+    the smallest candidate covering each axis, capped at the largest."""
+    assert kernel_blocks(100, 40) == (128, 64)
+    assert kernel_blocks(120, 33) == (128, 64)
+    assert kernel_blocks(1000, 500) == (256, 256)
+    assert kernel_blocks(24, 16, "bf16") == (64, 64)
 
 
-def test_autotune_timed_sweep_validates_and_picks_candidate():
-    """A timed sweep (forced on CPU/interpret) returns a candidate pair and
-    the fused kernel at that pair matches the oracle."""
-    kernel_autotune.clear_cache()
-    try:
-        bn, bm = autotune_blocks(24, 16, 2, timed=True, interpret=True)
-        assert bn in CANDIDATE_BLOCKS and bm in CANDIDATE_BLOCKS
-        K1, K2, mask, u = _mvm_problem(2, 24, 16, jnp.float32)
-        out = lk_mvm_fused(K1, K2, mask, u, 0.1, block_n=bn, block_m=bm,
-                           interpret=True)
-        ref = lk_mvm_ref(K1, K2, mask, u, 0.1)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-    finally:
-        kernel_autotune.clear_cache()
+def _kernel_calls(fn, *shapes):
+    """The pallas_call equations the trace of ``fn`` at ``shapes`` holds."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for p in eqn.params.values():
+                if isinstance(p, ClosedJaxpr):
+                    yield from walk(p.jaxpr)
+                elif isinstance(p, Jaxpr):
+                    yield from walk(p)
+
+    specs = [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
+    return list(walk(jax.make_jaxpr(fn)(*specs).jaxpr))
 
 
-def test_lk_mvm_pallas_inside_cg():
+CELL_PLANS = [
+    # (n, m, B, blocks, SquarePlan fields): the two cells' shapes, at the
+    # fit's MLL solves (B = 17) and the posterior's solve (B = 65)
+    (2000, 52, 17, (256, 64), dict(bk=256, tn=2048, chunk=6, m8=56,
+                                   n_pad=2048)),
+    (2000, 52, 65, (256, 64), dict(bk=256, tn=2048, chunk=5, m8=56,
+                                   n_pad=2048)),
+    (3125, 200, 17, (256, 256), dict(bk=256, tn=1792, chunk=1, m8=200,
+                                     n_pad=3584)),
+    (3125, 200, 65, (256, 256), dict(bk=256, tn=1792, chunk=2, m8=200,
+                                     n_pad=3584)),
+]
+
+
+@pytest.mark.parametrize("n,m,B,blocks,fields", CELL_PLANS)
+def test_lk_mvm_fused_plan_at_cell_shapes(n, m, B, blocks, fields):
+    """The blocks and square plan lk_mvm_fused takes unaided at the
+    benchmark cells' shapes: one kernel call whose output is the plan's
+    (B, m8, n_pad) and whose grid is the plan's (traced, not run)."""
+    assert kernel_blocks(n, m) == blocks
+    plan = square_plan(n, m, B, blocks[0])
+    assert plan == SquarePlan(**fields)
+    calls = _kernel_calls(lambda K1, K2, mask, u: lk_mvm_fused(
+        K1, K2, mask, u, 0.1), (n, n), (m, m), (n, m), (B, n, m))
+    assert len(calls) == 1
+    assert calls[0].outvars[0].aval.shape == (B, plan.m8, plan.n_pad)
+    assert tuple(calls[0].params["grid_mapping"].grid) == plan.grid(B)
+
+
+@pytest.mark.parametrize("B", [1, 3])
+def test_lk_mvm_fused_takes_row_strip_where_square_does_not_fit(B):
+    """At (n, m) = (16, 1152) K2 is too wide for the square layout; with
+    no blocks given lk_mvm_fused runs the row-strip kernel at the
+    heuristic's (64, 256) and matches the XLA body."""
+    n, m = 16, 1152
+    assert kernel_blocks(n, m) == (64, 256)
+    assert square_plan(n, m, B, 64) is None
+    K1, K2, mask, u = _mvm_problem(B, n, m, jnp.float32)
+    calls = _kernel_calls(lambda K1, K2, mask, u: lk_mvm_fused(
+        K1, K2, mask, u, 0.2), (n, n), (m, m), (n, m), (B, n, m))
+    assert len(calls) == 1
+    assert calls[0].outvars[0].aval.shape == (B, n, 1280)  # m to 5 x 256
+    out = lk_mvm_fused(K1, K2, mask, u, 0.2, interpret=True)
+    ref = lk_mvm_xla(K1, K2, mask, u, 0.2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_pallas_lk_mvm_inside_cg():
     """The Pallas MVM is a drop-in operator for the CG solver."""
     from functools import partial
 
@@ -275,7 +308,7 @@ def test_lk_mvm_pallas_inside_cg():
 
     K1, K2, mask, u = _mvm_problem(1, 24, 18, jnp.float32)
     b = u[0]
-    A_pallas = partial(lk_mvm_pallas, K1, K2, mask, noise=0.5, block_n=16,
+    A_pallas = partial(lk_mvm_fused, K1, K2, mask, noise=0.5, block_n=16,
                        block_m=16, interpret=True)
     A_ref = lk_operator(K1, K2, mask, 0.5)
     x1 = cg_solve(A_pallas, b, tol=1e-5, max_iters=500).x
